@@ -1,7 +1,7 @@
 """The paper's primary contribution, adapted to JAX: MANA-style transparent,
 topology-agnostic (M×N) checkpoint/restart with production hardening —
 coordinator with keepalive, two-phase atomic commit, drain protocol,
-two-tier storage, buddy redundancy, codecs, preemption, AOT restart cache.
+two-tier storage, buddy redundancy, codecs, preemption.
 See DESIGN.md for the paper↔module map (P1–P12).
 """
 from .atomic import CrashInjector, CrashPoint

@@ -29,12 +29,14 @@ backends that compute byte-identical candidates:
            SaveSession overlaps the scan of payload k+1 with the chunk
            hash/write of payload k;
 
-  pallas   the same blocked scan as an explicit accelerator kernel (one
-           grid program per block, halo block passed alongside) for
-           GPU/TPU hosts, where the gather+cumsum runs at HBM bandwidth.
-           On hosts without an accelerator it falls back to ``jnp`` (a
-           one-time warning); correctness is pinned by interpret-mode
-           parity tests.
+  pallas   the same scan as an explicit accelerator kernel written to
+           the TPU compiler's rules: lane-dense rows of 128 bytes, each
+           seen beside the row ahead of it, int32 window sums built by
+           doubling lane shifts, and the gear table looked up by two
+           in-register 128-lane gathers. Requested explicitly on a host
+           without an accelerator it falls back to ``jnp`` (a one-time
+           warning); correctness is pinned by interpret-mode parity tests
+           here and by a byte-parity phase on the chip (``chip_smoke.py``).
 
 Backend choice is a knob (``GearChunker(scan_backend=...)``), with
 ``auto`` picking pallas on accelerator hosts, jnp for payloads large
@@ -292,21 +294,41 @@ class _JnpBackend:
 
 
 # ---------------------------------------------------------------------------
-# pallas backend — explicit accelerator kernel (GPU/TPU), jnp fallback
+# pallas backend — explicit TPU kernel, jnp fallback
 # ---------------------------------------------------------------------------
 
-PALLAS_BLOCK = 64 << 10      # bytes per grid program
+LANES = 128                  # TPU vreg width: rows of the kernel layout
+ROWS = 512                   # rows per grid program (a multiple of 32,
+                             # the uint8 sublane tile)
+PALLAS_BLOCK = ROWS * LANES  # bytes per grid program
+_HALO_ROWS = 32              # smallest uint8 block that holds the row
+                             # ahead of a program's first row
+
+
+def _shift_lanes(x, k: int):
+    """x[:, l - k] at lane l, zero for l < k (a static lane shift)."""
+    import jax.numpy as jnp
+    return jnp.concatenate(
+        [jnp.zeros((x.shape[0], k), x.dtype), x[:, :-k]], axis=1)
 
 
 def _pallas_scan_expr(padded, mask_strict, mask_loose, *,
                       interpret: bool = False):
-    """Traceable blocked gear scan as a Pallas kernel: one grid program
-    per ``PALLAS_BLOCK`` span, with the *previous* block passed as a
-    second input so each program sees its 64-byte halo (program 0 reads
-    itself; its halo region falls below the first full window and is
-    discarded by extraction). Emits the same 0/loose/strict mask byte per
-    position as the jnp backend. Shared by the jitted segment scan and
-    the fused transform+scan dispatch."""
+    """Traceable blocked gear scan as a Pallas kernel. The padded span
+    is laid out lane-dense as rows of ``LANES`` bytes, and each grid
+    program scans ``ROWS`` rows. A program sees each row beside the row
+    ahead of it: the window ending at any byte of the row starts at most
+    63 bytes back, inside those two rows. The row ahead of a program's
+    first row comes from a second, ``_HALO_ROWS``-row block of the same
+    input (program 0 reads its own rows there; those positions fall
+    below the first full window and extraction discards them).
+
+    TPU rules the body keeps: int32 arithmetic only (uint32 sums wrap
+    identically), the 256-entry gear table looked up as two 128-lane
+    in-register gathers plus a select, and windows summed by six
+    doubling lane shifts rather than a cumsum. Emits the same
+    0/loose/strict mask byte per position as the jnp backend. Shared by
+    the jitted segment scan and the fused transform+scan dispatch."""
     import functools
 
     import jax
@@ -315,30 +337,46 @@ def _pallas_scan_expr(padded, mask_strict, mask_loose, *,
 
     def kernel(gear_ref, halo_ref, main_ref, out_ref, *, mask_strict,
                mask_loose):
-        buf = jnp.concatenate([halo_ref[-WINDOW:], main_ref[...]])
-        g = jnp.take(gear_ref[...], buf.astype(jnp.int32))
-        c = jnp.cumsum(g, dtype=jnp.uint32)            # wraps mod 2^32
-        w = c[WINDOW:] - c[:-WINDOW]
-        h = w & jnp.uint32(mask_strict)
-        out_ref[...] = ((h & jnp.uint32(mask_loose)) == 0) \
-            .astype(jnp.uint8) + (h == 0).astype(jnp.uint8)
+        x = main_ref[...].astype(jnp.int32)                 # [ROWS, 128]
+        prev = jnp.concatenate(
+            [halo_ref[_HALO_ROWS - 1:, :].astype(jnp.int32), x[:-1]],
+            axis=0)                                         # row r - 1
+        lo_tab = jnp.broadcast_to(gear_ref[0:1, :], x.shape)
+        hi_tab = jnp.broadcast_to(gear_ref[1:2, :], x.shape)
+
+        def gear(b):
+            i = b & (LANES - 1)
+            return jnp.where(b >= LANES,
+                             jnp.take_along_axis(hi_tab, i, axis=1),
+                             jnp.take_along_axis(lo_tab, i, axis=1))
+
+        s = jnp.concatenate([gear(prev), gear(x)], axis=1)  # [ROWS, 256]
+        k = 1
+        while k < WINDOW:                   # s[l] = sum of lanes l-2k+1..l
+            s = s + _shift_lanes(s, k)
+            k *= 2
+        h = s[:, LANES:] & mask_strict
+        out_ref[...] = (((h & mask_loose) == 0).astype(jnp.int32)
+                        + (h == 0).astype(jnp.int32)).astype(jnp.uint8)
 
     n = padded.shape[0]
-    grid = (n // PALLAS_BLOCK,)
+    rows = padded.reshape(n // LANES, LANES)
+    per = ROWS // _HALO_ROWS
     return pl.pallas_call(
         functools.partial(kernel, mask_strict=mask_strict,
                           mask_loose=mask_loose),
-        grid=grid,
+        grid=(n // PALLAS_BLOCK,),
         in_specs=[
-            pl.BlockSpec((256,), lambda i: (0,)),          # gear table
-            pl.BlockSpec((PALLAS_BLOCK,),
-                         lambda i: (jnp.maximum(i - 1, 0),)),  # halo
-            pl.BlockSpec((PALLAS_BLOCK,), lambda i: (i,)),
+            pl.BlockSpec((2, LANES), lambda i: (0, 0)),     # gear table
+            pl.BlockSpec((_HALO_ROWS, LANES),
+                         lambda i: (jnp.maximum(i * per - 1, 0), 0)),
+            pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((PALLAS_BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.uint8),
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint8),
         interpret=interpret,
-    )(jnp.asarray(GEAR), padded, padded)
+    )(jnp.asarray(GEAR.view(np.int32).reshape(2, LANES)), rows,
+      rows).reshape(n)
 
 
 def _pallas_scan_fn(interpret: bool = False):
@@ -392,11 +430,8 @@ class _PallasBackend:
 
 
 def accelerator_present() -> bool:
-    try:
-        import jax
-        return jax.default_backend() in ("gpu", "tpu", "cuda", "rocm")
-    except Exception:  # noqa — no usable jax: numpy oracle still works
-        return False
+    import jax
+    return jax.default_backend() in ("gpu", "tpu", "cuda", "rocm")
 
 
 # ---------------------------------------------------------------------------
@@ -425,7 +460,13 @@ def _build_fused_fn(backend: str, interpret: bool = False,
     byteplane transform is a global permutation of the stream, so
     per-segment halos would not compose across it. jax caches one
     executable per payload length — a training job's shard shapes form a
-    small fixed set, so recompilation is bounded in practice."""
+    small fixed set, so recompilation is bounded in practice. Device
+    memory grows with the payload: compiled for TPU v5e at 8 MiB, the
+    jnp transform+scan takes about 64 B of temporaries per payload byte
+    and the rANS stage about 136 B (the Pallas transform+scan none), so
+    a multi-GB leaf does not fit beside a training state. Nothing here
+    routes such a payload elsewhere: its allocation error reaches the
+    caller."""
     import jax
     import jax.numpy as jnp
 
@@ -585,7 +626,9 @@ class ScanTicket:
         self._extract = extract
         self._done = done               # eager backends resolve immediately
 
-    def result(self):
+    def result(self, on_segment=None):
+        """``on_segment()``, if given, is called after each segment is
+        extracted (the save path's writer heartbeat)."""
         if self._done is None:
             strict, loose = [], []
             while self._pending:
@@ -597,6 +640,8 @@ class ScanTicket:
                 _ARENA.release(buf)
                 strict.append(s)
                 loose.append(l)
+                if on_segment is not None:
+                    on_segment()
                 if self._todo:
                     nstart, nlen, ntotal = self._todo.pop(0)
                     self._pending.append(

@@ -14,8 +14,7 @@ import re
 from .errors import NamespaceError
 
 # lower-half reserved names (checkpoint machinery)
-RESERVED_PREFIXES = ("_META", ".tmp-", "LATEST", "_AOT_CACHE", "_DRAIN",
-                     "_CAS")
+RESERVED_PREFIXES = ("_META", ".tmp-", "LATEST", "_DRAIN", "_CAS")
 REPLICA_SUFFIX = ".r1"
 UPPER_DIR = "upper"
 

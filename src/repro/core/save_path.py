@@ -252,8 +252,11 @@ class SaveSession:
                 handle = self._chunker_obj.scanner.scan_async(payload)
 
                 def resolve(payload=payload, handle=handle):
+                    # a multi-GB payload scans as hundreds of segments:
+                    # beat per segment, not once per payload
                     return payload, self._chunker_obj.chunk(
-                        payload, candidates=handle.result())
+                        payload,
+                        candidates=handle.result(on_segment=self._on_chunk))
 
                 self._enqueue_scan(resolve, ticket)
             except BaseException:
@@ -537,6 +540,33 @@ class SaveSession:
 # phase-1 write engine (retrying, coordinator-supervised)
 # ---------------------------------------------------------------------------
 
+def call_with_heartbeat(fn, beat, interval_s: float):
+    """Run ``fn()`` on a helper thread while this thread calls ``beat()``
+    every ``interval_s``; return its result or raise its exception. A
+    whole-leaf encode (zstd over a multi-GB optimizer moment runs for
+    seconds per GB) would otherwise leave the rank silent past the
+    coordinator keepalive. The round's ``save_timeout_s`` still bounds a
+    call that never returns."""
+    box: dict = {}
+
+    def run():
+        try:
+            box["result"] = fn()
+        except BaseException as e:  # noqa — re-raised on the caller
+            box["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    while True:
+        t.join(interval_s)
+        if not t.is_alive():
+            break
+        beat()
+    if "error" in box:
+        raise box["error"]
+    return box["result"]
+
+
 class WriteOutcome:
     """Result of the phase-1 barrier: per-attempt stats, chunked records,
     the plan that produced them, and abort blame."""
@@ -621,8 +651,11 @@ def write_shards(*, items, alive_hint: int, coordinator, chunks: ChunkStore,
                                 .reshape(-1).view(np.uint8)
                             meta = {}
                         else:
-                            payload, meta = codec_mod.encode(arr,
-                                                             codec_name)
+                            payload, meta = call_with_heartbeat(
+                                lambda a=arr, c=codec_name:
+                                    codec_mod.encode(a, c),
+                                lambda: coordinator.heartbeat(rank),
+                                coordinator.keepalive_s / 4)
                         crash.maybe(f"rank{rank}_before_write")
                         ticket = session.submit_payload(payload)
                     rec = {

@@ -19,10 +19,12 @@ Backends mirror ``core.cdc_scan``'s three-backend structure:
            (the fused scan dispatch inlines ``forward_expr`` ahead of the
            gear-scan columns), plus jitted standalone entry points;
   pallas   explicit accelerator kernels. The forward kernel consumes the
-           element rows and their one-element-shifted copy (built by XLA,
-           which fuses the shift into the feeding pipeline) and writes the
-           transposed delta planes per grid block. The inverse is one grid
-           program per byte plane — the per-plane cumsum carry is
+           stream and its one-element-shifted copy (built by XLA) in
+           lane-dense blocks and gathers each plane's delta bytes inside
+           the block, so no (elements, itemsize) array is ever laid out.
+           The inverse is one grid program per byte plane, and the TPU
+           compiler refuses its (1, ne) block; nothing on the save or
+           restore path calls it — the per-plane cumsum carry is
            inherently sequential, so each program owns a whole plane
            (VMEM-bounded: fine for shard-sized payloads; the restore path
            uses the host oracle anyway and this kernel exists for backend
@@ -44,7 +46,9 @@ from jax.experimental import pallas as pl
 from ...core.codec import byteplane_forward, byteplane_inverse  # noqa: F401
 # ^ oracle re-export (the ref implementations, like .ref for the quantizer)
 
-BLOCK_ELEMS = 64 << 10      # forward-kernel elements per grid program
+LANES = 128                 # forward-kernel layout: rows of one vreg width
+BLOCK_ROWS = 512            # forward-kernel stream rows per grid program
+                            # (a multiple of 32 × every itemsize ≤ 16)
 
 
 # ---------------------------------------------------------------------------
@@ -92,32 +96,25 @@ def inverse_jnp(u8, *, itemsize: int):
 # pallas kernels
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(x_ref, p_ref, o_ref):
-    # x: (block, k) element rows; p: the same rows shifted one element
-    # down (row 0 of the stream is zeros, so d[0] = x[0] like the oracle)
-    o_ref[...] = (x_ref[...] - p_ref[...]).T
-
-
-def forward_planes_2d(x, prev, *, block_elems: int = BLOCK_ELEMS,
-                      interpret: bool = False):
-    """(ne, k) element rows + shifted rows → (k, ne) delta planes."""
-    ne, k = x.shape
-    block = min(block_elems, max(ne, 1))
-    pad = (-ne) % block
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        prev = jnp.pad(prev, ((0, pad), (0, 0)))
-    grid = ((ne + pad) // block,)
-    out = pl.pallas_call(
-        _fwd_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block, k), lambda i: (i, 0)),
-                  pl.BlockSpec((block, k), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((k, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((k, ne + pad), jnp.uint8),
-        interpret=interpret,
-    )(x, prev)
-    return out[:, :ne]
+def _fwd_kernel(x_ref, s_ref, o_ref, *, k: int):
+    # x: stream rows; s: the same rows shifted one element (k bytes).
+    # TPU rules: subtract in int32 (no uint8 vector subtract) keeping the
+    # low byte — the oracle's mod-256 delta — and gather each plane's
+    # bytes within 128-lane rows: byte p of the block's element e is
+    # stream byte e*k + p, in row e*k // LANES at lane (e*k + p) % LANES
+    rows = x_ref.shape[0] // k                    # output rows per plane
+    d = (x_ref[...].astype(jnp.int32) - s_ref[...].astype(jnp.int32)) & 0xFF
+    d = d.reshape(rows, k, LANES)                 # row j*k + q → [j, q]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    src_row = lane // (LANES // k)
+    for p in range(k):
+        idx = (lane * k + p) % LANES
+        plane = jnp.take_along_axis(d[:, 0, :], idx, axis=1)
+        for q in range(1, k):
+            plane = jnp.where(src_row == q,
+                              jnp.take_along_axis(d[:, q, :], idx, axis=1),
+                              plane)
+        o_ref[p] = plane.astype(jnp.uint8)
 
 
 def _inv_kernel(d_ref, o_ref):
@@ -141,15 +138,38 @@ def inverse_planes_2d(d, *, interpret: bool = False):
 
 def forward_pallas_expr(u8, itemsize: int, *, interpret: bool = False):
     """Traceable pallas forward (the fused pallas scan dispatch inlines
-    this, mirroring ``forward_expr`` on the jnp side)."""
+    this, mirroring ``forward_expr`` on the jnp side). The stream and its
+    one-element-shifted copy enter the kernel lane-dense, as rows of
+    ``LANES`` bytes padded to whole ``BLOCK_ROWS``-row grid blocks; the
+    kernel writes every plane's share of a block."""
     n = u8.shape[0]
     k = int(itemsize)
     ne = n // k
     if ne == 0:
         return u8
-    x = u8[:ne * k].reshape(ne, k)
-    prev = jnp.concatenate([jnp.zeros((1, k), jnp.uint8), x[:-1]])
-    d = forward_planes_2d(x, prev, interpret=interpret).reshape(-1)
+    if LANES % k:
+        raise ValueError(f"itemsize {k} does not divide {LANES}")
+    body = u8[:ne * k]
+    shifted = jnp.concatenate([jnp.zeros(k, jnp.uint8), body[:-k]])
+    rows = -(-ne * k // LANES)
+    # a whole number of 32-row uint8 tiles per plane in every block
+    block = min(BLOCK_ROWS, -(-rows // (32 * k)) * 32 * k)
+    rows_p = -(-rows // block) * block
+
+    def lay_out(a):
+        return jnp.pad(a, (0, rows_p * LANES - ne * k)).reshape(rows_p,
+                                                                LANES)
+
+    spec = pl.BlockSpec((block, LANES), lambda i: (i, 0))
+    out = pl.pallas_call(
+        partial(_fwd_kernel, k=k),
+        grid=(rows_p // block,),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec((k, block // k, LANES), lambda i: (0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, rows_p // k, LANES), jnp.uint8),
+        interpret=interpret,
+    )(lay_out(body), lay_out(shifted))
+    d = out.reshape(k, rows_p // k * LANES)[:, :ne].reshape(-1)
     return jnp.concatenate([d, u8[ne * k:]])
 
 

@@ -6,8 +6,8 @@ framing is defined THERE — every backend must produce byte-identical
 streams (property-fuzzed in tests/test_entropy.py).
 
 Structure mirrors ``byteplane.py``: the Pallas backend runs a real kernel
-for the per-block RLE emission pass (one grid program per 4 KiB plane
-block — runs never span blocks, so there is no halo) and shares the
+for the per-block RLE emission pass (one grid program per 32 rows of 4 KiB
+plane blocks — runs never span blocks, so there is no halo) and shares the
 traceable jnp glue (pair compaction, histogram, lane-interleaved rANS
 scan, serialization, block-choice and final stream compaction) with the
 jnp backend. Both exprs are inlined by the fused scan+transform+encode
@@ -53,8 +53,7 @@ def _block_layout(n: int):
 # value) pair iff the run ends at i or the cap is hit. Output is the
 # per-position emit mask and capped run length; compaction is shared glue.
 
-def _emission_common(x, idx, change, end, blen_last):
-    seg_start = jax.lax.cummax(jnp.where(change, idx, 0), axis=1)
+def _emission_common(seg_start, idx, end, blen_last):
     pos = idx - seg_start
     end = end | (idx == blen_last)       # partial last block ends its run
     emit = end | (pos % 255 == 254)
@@ -69,36 +68,54 @@ def _rle_emission_expr(blkmat, blens_np):
     one = jnp.ones((nb, 1), bool)
     change = jnp.concatenate([one, blkmat[:, 1:] != blkmat[:, :-1]], axis=1)
     end = jnp.concatenate([change[:, 1:], one], axis=1)
+    seg_start = jax.lax.cummax(jnp.where(change, idx, 0), axis=1)
     last = jnp.asarray((blens_np - 1).astype(np.int32))[:, None]
-    return _emission_common(blkmat, idx, change, end, last)
+    return _emission_common(seg_start, idx, end, last)
+
+
+ROWS = 32                  # plane blocks per grid program (the uint8
+                           # sublane tile)
 
 
 def _rle_kernel(n, x_ref, emit_ref, run_ref):
-    b = pl.program_id(0)
-    x = x_ref[...]                                      # [1, B]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-    one = jnp.ones((1, 1), bool)
-    change = jnp.concatenate([one, x[:, 1:] != x[:, :-1]], axis=1)
-    end = jnp.concatenate([change[:, 1:], one], axis=1)
-    emit, run = _emission_common(x, idx, change, end, n - 1 - b * B)
+    # TPU rules: int32 arithmetic, lane shifts by static slices of int32
+    # (never of bool), and the run-start prefix max as twelve doubling
+    # steps rather than a cummax
+    x = x_ref[...].astype(jnp.int32)                    # [ROWS, B]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    row = pl.program_id(0) * ROWS + \
+        jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    prev = jnp.concatenate([x[:, :1], x[:, :-1]], axis=1)
+    nxt = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
+    change = (idx == 0) | (x != prev)
+    end = (idx == B - 1) | (x != nxt)
+    seg_start = jnp.where(change, idx, 0)
+    k = 1
+    while k < B:
+        seg_start = jnp.maximum(seg_start, jnp.concatenate(
+            [jnp.zeros((ROWS, k), jnp.int32), seg_start[:, :-k]], axis=1))
+        k *= 2
+    emit, run = _emission_common(seg_start, idx, end, n - 1 - row * B)
     emit_ref[...] = emit
     run_ref[...] = run
 
 
 def _rle_emission_pallas(blkmat, n, *, interpret=False):
-    """Pallas emitter: one grid program per plane block."""
+    """Pallas emitter: one grid program per ``ROWS`` plane blocks (the
+    block matrix is padded with zero rows to a whole number of them)."""
     nb = blkmat.shape[0]
-    spec = pl.BlockSpec((1, B), lambda b: (b, 0))
+    nb_p = -(-nb // ROWS) * ROWS
+    spec = pl.BlockSpec((ROWS, B), lambda b: (b, 0))
     emit, run = pl.pallas_call(
         partial(_rle_kernel, n),
-        grid=(nb,),
+        grid=(nb_p // ROWS,),
         in_specs=[spec],
         out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((nb, B), jnp.bool_),
-                   jax.ShapeDtypeStruct((nb, B), jnp.uint8)],
+        out_shape=[jax.ShapeDtypeStruct((nb_p, B), jnp.bool_),
+                   jax.ShapeDtypeStruct((nb_p, B), jnp.uint8)],
         interpret=interpret,
-    )(blkmat)
-    return emit, run
+    )(jnp.pad(blkmat, ((0, nb_p - nb), (0, 0))))
+    return emit[:nb], run[:nb]
 
 
 # ---------------------------------------------------------------------------

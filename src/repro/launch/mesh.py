@@ -6,28 +6,23 @@ device state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType landed after 0.4.37; explicit Auto axis types
-    # are the default behaviour on older runtimes anyway
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_host_mesh(shape=None, axes=None):
-    """Small mesh over whatever devices exist (tests/examples on CPU)."""
-    n = len(jax.devices())
+def make_host_mesh(shape=None, axes=None, devices=None):
+    """Small mesh over ``devices`` (default: every device this process
+    sees) — one host's chips, or the CPU devices of a test."""
+    devices = jax.devices() if devices is None else list(devices)
     if shape is None:
-        shape, axes = (n, 1), ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+        shape, axes = (len(devices), 1), ("data", "model")
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware model for the roofline (assigned constants).

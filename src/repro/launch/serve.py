@@ -28,6 +28,7 @@ from ..core.policy import CheckpointPolicy
 from ..core.storage import default_store
 from ..models import Model
 from ..train.steps import make_serve_fns
+from .compile_cache import enable_compile_cache
 
 log = logging.getLogger("repro.serve")
 
@@ -168,6 +169,7 @@ def main(argv=None):
                     help="subscriber name published back to the source "
                          "(inspect_ckpt --subscribers)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     rep = run(args.arch, n_requests=args.requests,
               prompt_len=args.prompt_len, gen_len=args.gen_len,

@@ -1,10 +1,9 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
-Runs a REDUCED config end-to-end on local devices (the full configs are
-exercised by the dry-run; this box is CPU-only). Demonstrates the paper's
-full production path: restore-on-start → train → periodic async checkpoints
-→ preempt-safe exit, with the AOT compile cache standing in for
-statically-linked-binary startup.
+Runs a REDUCED config end-to-end on local devices by default, the full
+published config with ``--full-config``. Demonstrates the paper's full
+production path: restore-on-start → train → periodic async checkpoints
+→ preempt-safe exit.
 """
 from __future__ import annotations
 
@@ -14,6 +13,7 @@ import logging
 from ..configs import ARCH_IDS, get_config, reduced
 from ..core.codec import CODECS
 from ..train.loop import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -68,10 +68,11 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--sync-ckpt", action="store_true")
     ap.add_argument("--full-config", action="store_true",
-                    help="use the full-size config (only sane on real pods)")
+                    help="use the full-size published config")
     ap.add_argument("--preset", action="store_true",
                     help="apply the per-arch production parallelism preset")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     logging.basicConfig(
         level=logging.INFO,

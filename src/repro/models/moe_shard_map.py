@@ -24,30 +24,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map as _shard_map       # jax ≥ 0.6 top-level fn
-except ImportError:                               # 0.4.x experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-
-import inspect as _inspect
-
-try:
-    _CHECK_KW = ("check_vma"
-                 if "check_vma" in _inspect.signature(_shard_map).parameters
-                 else "check_rep")
-except (ValueError, TypeError):        # builtins without a signature
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version shim: the experimental 0.4.x API spells check_vma as
-    check_rep; everything else matches. The kwarg is probed once at import
-    — a per-call try/except TypeError would mask genuine TypeErrors from
-    bad specs as a confusing check_rep error."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_vma})
 
 from .layers import _act
 from .moe import _positions_in_expert, capacity

@@ -104,7 +104,10 @@ def ssd_forward(params, x, cfg, *, state=None, return_state=False):
         seg = cum[:, :, None] - cum[:, None, :]      # (B,i,j,nh)
         seg = jnp.transpose(seg, (0, 3, 1, 2))       # (B,nh,i,j)
         mask = jnp.tril(jnp.ones((L, L), bool))
-        M = jnp.where(mask, jnp.exp(seg), 0.0)
+        # mask BEFORE exp: above the diagonal seg > 0 grows with the chunk
+        # (hundreds at chunk 256), exp overflows to inf, and the masked
+        # gradient 0 * inf would be NaN
+        M = jnp.exp(jnp.where(mask, seg, -jnp.inf))
         y = jnp.einsum("bhij,bjhp->bihp", cb * M, xdt)
         # inter-chunk contribution from carried state
         y = y + jnp.einsum("bihn,bhpn->bihp",

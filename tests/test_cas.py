@@ -2,6 +2,8 @@
 semantics (dedup, replicas, corruption), refcount invariants across
 save/save/gc, and the headline dedup guarantee — re-saving identical state
 writes ~0 new object bytes."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -342,3 +344,22 @@ def test_gc_never_deletes_live_chunks(tmp_path):
         restored, _ = mgr.restore(_abstract(state), step=s)
         np.testing.assert_array_equal(states[s - 1]["params"]["w"],
                                       np.asarray(restored["params"]["w"]))
+
+
+def test_slow_leaf_encode_keeps_rank_alive(tmp_path, monkeypatch):
+    """A whole-leaf encode longer than the keepalive (zstd over a
+    multi-GB optimizer moment takes seconds per GB) is a busy rank, not a
+    dead one: the writer beats while the encode runs, and the save
+    commits without a retry."""
+    real = codec_mod.encode
+
+    def slow_encode(arr, codec):
+        time.sleep(1.0)
+        return real(arr, codec)
+
+    monkeypatch.setattr(codec_mod, "encode", slow_encode)
+    mgr = _mgr(tmp_path, codec="int8", keepalive_s=0.4, max_retries=0)
+    state = _state()
+    rep = mgr.save(state, 1)
+    assert mgr.coordinator.metrics["keepalive_timeouts"] == 0
+    assert mgr.latest_step() == 1 and rep["step"] == 1

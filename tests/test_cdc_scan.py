@@ -115,6 +115,21 @@ def test_scan_async_matches_sync(rng):
         assert s2 is s and l2 is l
 
 
+def test_scan_result_reports_each_segment(rng):
+    """``result(on_segment=...)`` fires once per extracted segment — the
+    save path's writer heartbeat through a multi-GB payload scan."""
+    ms, ml = _masks()
+    sc = GearScanner(ms, ml, backend="jnp")
+    n_seg = cdc_scan.MAX_INFLIGHT_SEGMENTS + 2
+    payload = rng.bytes(cdc_scan.SEGMENT_BYTES * (n_seg - 1) + 1000)
+    beats = []
+    s, l = sc.scan_async(payload).result(on_segment=lambda: beats.append(1))
+    assert len(beats) == n_seg
+    rs, rl = GearScanner(ms, ml, backend="numpy").scan(payload)
+    np.testing.assert_array_equal(s, rs)
+    np.testing.assert_array_equal(l, rl)
+
+
 def test_auto_backend_size_gate(rng):
     ms, ml = _masks()
     sc = GearScanner(ms, ml, backend="auto")

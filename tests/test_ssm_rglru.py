@@ -43,6 +43,21 @@ def test_ssd_state_carry_across_segments():
     assert jnp.max(jnp.abs(st2["h"] - st_full["h"])) < 1e-3
 
 
+def test_ssd_grads_finite_at_published_chunk():
+    """mamba2-780m's published chunk (256): decay sums above the diagonal
+    of a chunk reach hundreds, so exp() of them overflows — the masked
+    entries must not turn the gradient into NaN."""
+    from dataclasses import replace
+    base = reduced(CONFIGS["mamba2-780m"])
+    cfg = replace(base, ssm=replace(base.ssm, chunk_size=256))
+    params = ssm_mod.init_ssm(KEY, cfg)
+    x = jax.random.normal(KEY, (1, 256, cfg.d_model)) * 0.5
+    grads = jax.grad(lambda p: jnp.sum(ssm_mod.ssd_forward(p, x, cfg)))(
+        params)
+    for g in jax.tree.leaves(grads):
+        assert bool(jnp.all(jnp.isfinite(g)))
+
+
 def test_rglru_scan_equals_recurrent():
     cfg = reduced(CONFIGS["recurrentgemma-9b"])
     params = rglru_mod.init_rglru(KEY, cfg)

@@ -1,5 +1,4 @@
-"""End-to-end behaviour of the paper's system: serve-with-C/R and the
-AOT restart cache (startup-time lesson)."""
+"""End-to-end behaviour of the paper's system: serve-with-C/R."""
 import jax
 import numpy as np
 import pytest
@@ -61,21 +60,3 @@ def test_serve_hot_swaps_published_weights(tmp_path):
     assert swapped["status"] == "completed"
     assert swapped["weight_sync_step"] == 0
     assert not np.array_equal(swapped["tokens"], base["tokens"])
-
-
-def test_aot_cache_roundtrip(tmp_path):
-    """Static-linking analogue: second bring-up loads the serialized
-    executable instead of recompiling (falls back gracefully if the backend
-    can't serialize)."""
-    from repro.core.aot_cache import AotCache
-    cache = AotCache(tmp_path / "aot")
-    fn = jax.jit(lambda x: x * 2 + 1)
-    import jax.numpy as jnp
-    args = (jnp.ones((8, 8)),)
-    c1, src1 = cache.load_or_compile(fn, args, tag="t")
-    assert src1 == "compile"
-    if cache.stats["stores"]:
-        c2, src2 = cache.load_or_compile(fn, args, tag="t")
-        assert src2 == "cache"
-        np.testing.assert_array_equal(np.asarray(c2(*args)),
-                                      np.asarray(c1(*args)))
