@@ -15,9 +15,9 @@ One process drives the chip; it starts no other.
       an async incremental CDC checkpoint every 2 steps;
   (d) a fresh ``Trainer`` on the same work directory restores, matches
       the params digest of (c), and takes one more step;
-  (e) with ``--chips 4``: 2 steps on a 4-chip mesh and a save, then a
-      restore onto a 1-chip mesh in the same process (M×N restart), an
-      equal digest and one more step.
+  (e) with ``--chips 4``: 2 steps on the Trainer's default mesh over the
+      four chips and a save, then a restore onto a 1-chip mesh in the same
+      process (M×N restart), an equal digest and one more step.
 
 Every phase prints its lines as it goes. The last line of standard output
 is one JSON object, ``{"ok": true, "device": {...}}``; a run that fails
@@ -42,10 +42,13 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 WORKDIR = ROOT / ".smoke_run"
 ARCH = "mamba2-780m"
+# the seed of the weights, the data and the codec payloads; the digests
+# and losses recorded for this run hold for it
+SEED = 0
 # batch × sequence chosen from the compiled step's memory_analysis() on a
-# described v5e chip: 8 × 512 needs 7.27 GiB of state + 5.57 GiB of temps
-# of 15.75 GiB; 8 × 1024 needs 15.67 GiB, too close to the limit. The
-# sequence is a multiple of the SSM chunk (256).
+# described v5e chip: 8 × 512 needs 7.27 GiB of arguments + 3.99 GiB of
+# temporaries of 15.75 GiB; 8 × 1024 needs 7.27 + 9.29 GiB, more than
+# the chip holds. The sequence is a multiple of the SSM chunk (256).
 BATCH, SEQ_LEN = 8, 512
 SCAN_BYTES = 16 << 20
 FUSED_BYTES = 8 << 20
@@ -136,8 +139,31 @@ def _mib(n) -> str:
 
 def _hbm_peak(dev) -> str:
     stats = dev.memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
-    return "not reported" if peak is None else _gib(peak)
+    peak, limit = stats.get("peak_bytes_in_use"), stats.get("bytes_limit")
+    if peak is None:
+        return "not reported"
+    return _gib(peak) + ("" if limit is None else f" of {_gib(limit)}")
+
+
+def step_memory(trainer) -> str:
+    """What the compiler counts for the trainer's own step program, lowered
+    with its live state and batch layout: the program the steps ran, which
+    the compile cache serves when it is on."""
+    import jax
+
+    from repro.sharding.partition import batch_spec
+
+    def shape(x, sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    state = jax.tree.map(lambda x: shape(x, x.sharding), trainer.state)
+    batch, _ = trainer.pipeline.next(trainer.data_state)
+    batch = jax.tree.map(shape, batch, batch_spec(batch, trainer.mesh))
+    m = trainer.step_fn.lower(state, batch).compile().memory_analysis()
+    return (f"arguments {_gib(m.argument_size_in_bytes)}, outputs "
+            f"{_gib(m.output_size_in_bytes)} (aliased "
+            f"{_gib(m.alias_size_in_bytes)}), temporaries "
+            f"{_gib(m.temp_size_in_bytes)}")
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +203,7 @@ def phase_env(cache_dir) -> list:
 # (b) device codec parity
 # ---------------------------------------------------------------------------
 
-def phase_codec(seed: int, clock: CompileClock, *, scan_bytes=SCAN_BYTES,
+def phase_codec(clock: CompileClock, *, scan_bytes=SCAN_BYTES,
                 fused_bytes=FUSED_BYTES) -> set:
     """Byte parity of every device codec program with its numpy oracle.
     Returns the set of backends ``resolve()`` chose."""
@@ -193,7 +219,7 @@ def phase_codec(seed: int, clock: CompileClock, *, scan_bytes=SCAN_BYTES,
     policy = CheckpointPolicy().with_overrides(chunking="cdc")
     sc = GearChunker.from_policy(policy.chunking).scanner
     ms, ml = sc.mask_strict, sc.mask_loose
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     backends = set()
 
     for dtype in (np.float32, jnp.bfloat16):
@@ -269,8 +295,7 @@ def state_bytes(cfg) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(abstract))
 
 
-def trainer_config(workdir: Path, seed: int, *, batch=BATCH,
-                   seq_len=SEQ_LEN):
+def trainer_config(workdir: Path, *, batch=BATCH, seq_len=SEQ_LEN):
     """The TrainerConfig ``launch/train.py`` builds for ``--ckpt-mode
     incremental --chunking cdc --ckpt-every 2``, with ``retain`` 2 (two
     ~7.8 GB saves on the fast tier plus their drain copies)."""
@@ -278,7 +303,7 @@ def trainer_config(workdir: Path, seed: int, *, batch=BATCH,
     return TrainerConfig(workdir=str(workdir / ARCH), batch=batch,
                          seq_len=seq_len, ckpt_every=2, retain=2,
                          ckpt_mode="incremental", chunking="cdc",
-                         seed=seed, log_every=1)
+                         seed=SEED, log_every=1)
 
 
 def train_and_save(phase, cfg, tcfg, steps: int, clock: CompileClock, *,
@@ -358,7 +383,7 @@ def restore_and_step(phase, cfg, tcfg, digest: str, step: int,
 
 # ---------------------------------------------------------------------------
 
-def run(chips: int, seed: int) -> dict:
+def run(chips: int) -> dict:
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
     import jax
@@ -366,7 +391,8 @@ def run(chips: int, seed: int) -> dict:
     devs = jax.devices()
     if devs[0].platform != "tpu":
         raise SmokeError(f"no TPU: JAX found {devs[0].platform} devices")
-    check(len(devs) >= chips, f"--chips {chips} but JAX sees {len(devs)}")
+    check(chips == 1 or len(devs) == chips,
+          f"--chips {chips} but JAX sees {len(devs)}")
     shutil.rmtree(WORKDIR, ignore_errors=True)
     WORKDIR.mkdir()
     failures = FailureWarnings()
@@ -381,28 +407,28 @@ def run(chips: int, seed: int) -> dict:
     say("a", f"{ARCH}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
              f"vocab {cfg.vocab_size}, {param_counts(cfg)['n_total']:,} "
              f"params; batch {BATCH} × seq {SEQ_LEN}")
-    tcfg = trainer_config(WORKDIR, seed)
+    tcfg = trainer_config(WORKDIR)
     say("a", f"disk for the run: each save writes up to "
              f"{_gib(state_bytes(cfg))} to the fast tier and again to its "
              f"drain copy; {_gib(shutil.disk_usage(WORKDIR).free)} free")
     if chips == 1:
-        backends = phase_codec(seed, clock)
+        backends = phase_codec(clock)
         check(backends == {"pallas"}, f"codec backends {backends}: the "
               "Pallas kernels are the TPU route")
         one_chip = make_host_mesh(devices=devs[:1])
         trainer, digest = train_and_save("c", cfg, tcfg, 4, clock,
                                          mesh=one_chip)
+        say("c", f"step program per chip: {step_memory(trainer)}")
         say("c", f"HBM peak {_hbm_peak(devs[0])}")
         close(trainer)
         del trainer
         restore_and_step("d", cfg, tcfg, digest, 4, clock, mesh=one_chip)
         say("d", f"HBM peak {_hbm_peak(devs[0])}")
     else:
-        # a (2, 2) data × model mesh: the default (4, 1) layout, all-FSDP,
-        # makes the TPU compiler fail a scheduling RET_CHECK on this model
-        trainer, digest = train_and_save(
-            "e", cfg, tcfg, 2, clock,
-            mesh=make_host_mesh((2, 2), ("data", "model"), devs[:chips]))
+        # the Trainer's own mesh, as the launcher builds it on this host
+        trainer, digest = train_and_save("e", cfg, tcfg, 2, clock)
+        say("e", f"step program per chip: {step_memory(trainer)}")
+        say("e", f"HBM peak {_hbm_peak(devs[0])}")
         close(trainer)
         del trainer
         restore_and_step("e", cfg, tcfg, digest, 2, clock,
@@ -418,7 +444,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: only the 4-chip save → 1-chip restore phase")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     if not (ROOT / "src" / "repro").is_dir():
         print(f"chip_smoke: no repro package under {ROOT / 'src'}",
@@ -429,7 +454,7 @@ def main(argv=None) -> int:
                         format="%(asctime)s %(name)s %(levelname)s "
                                "%(message)s")
     try:
-        result = run(args.chips, args.seed)
+        result = run(args.chips)
     except Exception:  # noqa: BLE001 — every failure ends the run
         traceback.print_exc()
         print("chip_smoke: FAILED", file=sys.stderr)

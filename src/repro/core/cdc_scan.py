@@ -461,12 +461,14 @@ def _build_fused_fn(backend: str, interpret: bool = False,
     per-segment halos would not compose across it. jax caches one
     executable per payload length — a training job's shard shapes form a
     small fixed set, so recompilation is bounded in practice. Device
-    memory grows with the payload: compiled for TPU v5e at 8 MiB, the
-    jnp transform+scan takes about 64 B of temporaries per payload byte
-    and the rANS stage about 136 B (the Pallas transform+scan none), so
-    a multi-GB leaf does not fit beside a training state. Nothing here
-    routes such a payload elsewhere: its allocation error reaches the
-    caller."""
+    memory grows with the payload. Compiled for TPU v5e, the outputs
+    alone are twice the payload; ``memory_analysis()`` counts 64 B of
+    temporaries per payload byte for the jnp transform+scan at 8 MiB,
+    none for the Pallas transform+scan at 8 and 64 MiB, 8 B (8 MiB) to
+    24 B (64 MiB) for the Pallas RLE stage and 140 B for the rANS stage
+    at 8 MiB. A multi-GB leaf does not fit beside a training state, and
+    nothing here routes such a payload elsewhere: its allocation error
+    reaches the caller."""
     import jax
     import jax.numpy as jnp
 

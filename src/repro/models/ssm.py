@@ -122,7 +122,13 @@ def ssd_forward(params, x, cfg, *, state=None, return_state=False):
 
     xs_seq = (xc.transpose(1, 0, 2, 3, 4), Bc.transpose(1, 0, 2, 3, 4),
               Cc.transpose(1, 0, 2, 3, 4), dtc.transpose(1, 0, 2, 3))
-    h_final, yc = jax.lax.scan(chunk_body, h0, xs_seq)
+    # two chunks per loop trip: with the batch sharded over a data axis and
+    # the projections FSDP-sharded, the TPU compiler (libtpu 0.0.34) fails
+    # a scheduling RET_CHECK on the one-chunk loop's gradient (its async
+    # collective fusion in while loops). The unrolled loop compiles; it is
+    # the same computation, though XLA may fuse it differently and round
+    # in other places
+    h_final, yc = jax.lax.scan(chunk_body, h0, xs_seq, unroll=2)
     y = yc.transpose(1, 0, 2, 3, 4).reshape(B, S, nh, hd)
     y = y + params["D"][:, None] * xs.astype(jnp.float32)
     y = y.reshape(B, S, d_inner).astype(x.dtype)
