@@ -45,7 +45,7 @@ import threading
 import zlib
 from collections import Counter
 
-from . import atomic, resilience
+from . import atomic, resilience, trace
 from .atomic import NO_CRASH, CrashInjector
 from .chunk_exec import DEFAULT_IO_THREADS, ChunkIOExecutor, cpu_cap
 from .errors import CASError, CorruptShardError, MissingShardError, warn
@@ -387,7 +387,8 @@ class ChunkStore:
                     on_chunk=None, chunker=None,
                     want_crc: bool = False,
                     dirs_out: set | None = None,
-                    lens_out: list | None = None) -> tuple:
+                    lens_out: list | None = None,
+                    trace_id=None) -> tuple:
         """Chunk + store an encoded shard payload.
         Returns (digest_list, new_bytes_written).
 
@@ -420,17 +421,29 @@ class ChunkStore:
         chunk order — CDC shard records store the list so restore can
         compute every chunk's offset up front and place reads directly.
 
+        ``trace_id`` (serial engine; the pipelined one takes it through
+        its ``SaveSession``) names the ``ckpt.persist`` trace root of a
+        CDC chunker's scan (``ckpt.scan_wait``) and of each chunk's
+        digest and write (``ckpt.store``).
+
         The pipelined branch is ``save_path.SaveSession`` limited to one
         payload — ONE implementation of the windowed hash→write pipeline
         (crc folding, dir batching, mid-batch crash point, error-joins-all)
         serves both this call and the rank-wide streaming writer."""
         if self._exec.serial:
-            chunks = (run_chunker(chunker, payload) if chunker is not None
-                      else split_payload(payload, self.chunk_size))
+            if chunker is None:
+                chunks = split_payload(payload, self.chunk_size)
+            elif hasattr(chunker, "scanner"):
+                # a CDC chunker object: the writer waits on its scan
+                with trace.span("ckpt.scan_wait", trace_id):
+                    chunks = run_chunker(chunker, payload)
+            else:
+                chunks = run_chunker(chunker, payload)
             digests, new, crc = [], 0, 0
             for chunk in chunks:
-                d = chunk_digest(chunk)
-                new += self.put(d, chunk, crash)
+                with trace.span("ckpt.store", trace_id):
+                    d = chunk_digest(chunk)
+                    new += self.put(d, chunk, crash)
                 digests.append(d)
                 if lens_out is not None:
                     lens_out.append(len(chunk))

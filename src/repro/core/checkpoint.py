@@ -44,6 +44,7 @@ required to match (tested 1↔4↔8-device, both modes).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import shutil
 import time
@@ -56,7 +57,7 @@ import numpy as np
 
 from . import atomic, cas, cdc
 from . import codec as codec_mod
-from . import resilience, save_path
+from . import resilience, save_path, trace
 from .atomic import NO_CRASH, CrashInjector
 from .chunk_exec import ChunkIOExecutor, cpu_cap
 from .coordinator import CheckpointCoordinator
@@ -89,6 +90,9 @@ READABLE_FORMATS = (2, 3, 4, 5, 6, 7)
 # stages now, but these names have external users
 _pack_shard = pack_shard
 _unpack_shard = unpack_shard
+
+# trace ids of restores (a save round's is its step): one per call
+_RESTORE_IDS = itertools.count(1)
 
 
 class CheckpointManager:
@@ -243,58 +247,48 @@ class CheckpointManager:
         queued = (not blocking) and self._persist.depth > 1
         est = 0
         admit_s = 0.0
-        if queued:
-            # multi-round persist queue: block only for ADMISSION — a free
-            # in-flight slot under the host byte budget — so round N+1
-            # snapshots while round N persists. Estimated from device
-            # metadata because the budget gate must run BEFORE this
-            # round's host copy exists. A failed earlier round surfaces
-            # HERE (depth-1 parity: its wait() raises on the next save) —
-            # never silently, checkpoints after it would be a lie.
-            self._persist.raise_pending()
-            est = save_path.estimate_snapshot_bytes(state)
-            admit_s = self._persist.admit(est)
-        else:
-            # P4: quiescence before snapshot (depth-1 behaviour — and the
-            # serial engine's only path: byte-for-byte the PR-1 baseline)
-            self.wait()                              # previous round drained
-        degraded_hint = False
-        try:
-            wait_s = quiesce_device_state(state)
-            registry = build_registry(state)
-            items = self._snapshot(state)
-            snap_s = time.monotonic() - t0
-            total = sum(a.nbytes for _, _, a in items)
-            # P8 preflight must see the WHOLE queue's unwritten footprint:
-            # earlier admitted rounds' chunks may not have hit the tier
-            # yet, so their snapshot bytes (minus this round's own
-            # reservation) are added to the requirement
-            pending = max(self._persist.inflight_bytes - est, 0) \
-                if queued else 0
-            required = (total + pending) // max(self._est_ratio(), 1)
+        # stage 0 is the ckpt.save root; stages 1-3 are ckpt.persist under
+        # the same id (``_write_round``), on whichever thread runs them
+        with trace.root("ckpt.save", step):
+            reserved = False
             try:
-                self.store.fast.preflight(required)
-            except SpaceError:
-                # degraded-mode save (pipelined engine only): a full fast
-                # tier fails the round over to the hierarchy below instead
-                # of aborting — writers land objects via _put_degraded and
-                # the manifest commits with a `degraded` marker. Serial
-                # stays fail-fast (PR-1 purity).
-                fallback = self.store.slow or self.store.remote
-                if self.chunks.retry is None or fallback is None:
-                    raise
-                warn("CKPT_W_DEGRADED",
-                     "fast tier failed capacity preflight; saving "
-                     "degraded through the lower tier(s)",
-                     step=step, tier=fallback.name)
-                fallback.preflight(required)
-                degraded_hint = True
-        except BaseException:
-            if queued:
-                # the admission reservation must not leak — a stuck slot
-                # would wedge every later admit() at the depth bound
-                self._persist.release(est)
-            raise
+                with trace.span("ckpt.quiesce", step):
+                    if queued:
+                        # multi-round persist queue: block only for
+                        # ADMISSION — a free in-flight slot under the host
+                        # byte budget — so round N+1 snapshots while round
+                        # N persists. Estimated from device metadata
+                        # because the budget gate must run BEFORE this
+                        # round's host copy exists. A failed earlier round
+                        # surfaces HERE (depth-1 parity: its wait() raises
+                        # on the next save) — never silently, checkpoints
+                        # after it would be a lie.
+                        self._persist.raise_pending()
+                        est = save_path.estimate_snapshot_bytes(state)
+                        admit_s = self._persist.admit(est)
+                        reserved = True
+                    else:
+                        # P4: quiescence before snapshot (depth-1
+                        # behaviour — and the serial engine's only path:
+                        # byte-for-byte the PR-1 baseline)
+                        self.wait()                  # previous round drained
+                    quiesce_device_state(state)
+                with trace.span("ckpt.registry", step):
+                    registry = build_registry(state)
+                with trace.span("ckpt.snapshot", step):
+                    items = self._snapshot(state)
+                snap_s = time.monotonic() - t0
+                total = sum(a.nbytes for _, _, a in items)
+                trace.count(step, snapshot_bytes=total)
+                with trace.span("ckpt.preflight", step):
+                    degraded_hint = self._preflight(step, total, est, queued)
+            except BaseException:
+                if reserved:
+                    # the admission reservation must not leak — a stuck
+                    # slot would wedge every later admit() at the depth
+                    # bound
+                    self._persist.release(est)
+                raise
         self.counters.enqueue(total)
 
         # exactly-once counter drain for this round: the abort path inside
@@ -309,10 +303,15 @@ class CheckpointManager:
                 self.counters.commit(total)
 
         args = (items, registry, state, step, extra or {}, total, t0,
-                snap_s, wait_s, crash, commit_total, degraded_hint)
+                snap_s, crash, commit_total, degraded_hint)
+
+        def persist(overlapped: bool) -> dict:
+            with trace.root("ckpt.persist", step):
+                return self._write_round(*args, overlapped=overlapped)
+
         if blocking:
             try:
-                return self._write_round(*args, overlapped=False)
+                return persist(False)
             except BaseException:
                 # ANY failure (not just the abort path, which drains its
                 # own counters) must drain exactly once — e.g. an OSError
@@ -321,13 +320,41 @@ class CheckpointManager:
                 commit_total()
                 raise
         self._persist.submit(
-            lambda: self._write_round(*args, overlapped=True),
+            lambda: persist(True),
             # counters must still drain or the trainer deadlocks
             on_error=lambda e: commit_total(),
             nbytes=est, reserved=queued)
         return {"step": step, "async": True, "snapshot_s": snap_s,
                 "admit_s": admit_s,
                 "blocking_s": time.monotonic() - t0, "bytes": total}
+
+    def _preflight(self, step, total, est, queued) -> bool:
+        """P8 capacity preflight; True when the round must save degraded.
+        It must see the WHOLE queue's unwritten footprint: earlier
+        admitted rounds' chunks may not have hit the tier yet, so their
+        snapshot bytes (minus this round's own reservation) are added to
+        the requirement."""
+        pending = max(self._persist.inflight_bytes - est, 0) \
+            if queued else 0
+        required = (total + pending) // max(self._est_ratio(), 1)
+        try:
+            self.store.fast.preflight(required)
+        except SpaceError:
+            # degraded-mode save (pipelined engine only): a full fast tier
+            # fails the round over to the hierarchy below instead of
+            # aborting — writers land objects via _put_degraded and the
+            # manifest commits with a `degraded` marker. Serial stays
+            # fail-fast (PR-1 purity).
+            fallback = self.store.slow or self.store.remote
+            if self.chunks.retry is None or fallback is None:
+                raise
+            warn("CKPT_W_DEGRADED",
+                 "fast tier failed capacity preflight; saving "
+                 "degraded through the lower tier(s)",
+                 step=step, tier=fallback.name)
+            fallback.preflight(required)
+            return True
+        return False
 
     def _est_ratio(self):
         # plain byteplane is a size-preserving permutation — no entropy
@@ -436,7 +463,7 @@ class CheckpointManager:
         return self.codec
 
     def _write_round(self, items, registry, state, step, extra, total, t0,
-                     snap_s, wait_s, crash, commit_total,
+                     snap_s, crash, commit_total,
                      degraded_hint: bool = False,
                      overlapped: bool = False) -> dict:
         stage = atomic.staging_dir(self.store.root, step)
@@ -467,71 +494,77 @@ class CheckpointManager:
                                reason=outcome.reason)
         stats = outcome.stats
 
-        # ---- stage 2: manifest = commit record (single handle, P7) ----
-        leaf_specs = [(name, leaf.shape, str(leaf.dtype))
-                      for name, leaf in leaf_paths(state)]
-        leaves = outcome.plan.manifest_leaves(
-            leaf_specs, outcome.shard_records if incremental else None)
-        manifest = {
-            "format": FORMAT_VERSION,
-            "mode": self.mode,
-            "step": step,
-            "created": time.time(),
-            "chunk_size": self.chunks.chunk_size if incremental else None,
-            "chunking": self.chunking if incremental else None,
-            # CDC bound triple (min/avg/max): lets the inspector compare
-            # the realized chunk-size distribution against what was asked
-            "chunk_bounds": ([self._chunker.min_size, self._chunker.avg_size,
-                              self._chunker.max_size]
-                             if incremental and self._chunker is not None
-                             else None),
-            # v6: the writer's EFFECTIVE policy (codec resolved) rides the
-            # manifest, so a restarted job adopts the writer's
-            # chunking/scan/codec settings with zero caller configuration
-            "policy": self._effective_policy_dict(),
-            "leaves": leaves,
-            "registry": registry_json(registry),
-            "extra": extra,
-        }
-        degraded = bool(degraded_hint or
-                        self.chunks.degraded_writes > pre_degraded)
-        if degraded:
-            # only present when True: older readers' lenient from_dict
-            # ignores the key, and clean manifests stay byte-identical
-            manifest["degraded"] = True
-            warn("CKPT_W_DEGRADED",
-                 "round committed degraded: objects written past the "
-                 "fast tier; restore reads them from the lower tier(s)",
-                 step=step,
-                 objects=self.chunks.degraded_writes - pre_degraded)
-        crash.maybe("before_manifest")
-        atomic.atomic_write_bytes(stage / atomic.MANIFEST,
-                                  json.dumps(manifest).encode(), crash)
-        atomic.clear_pending(stage)
-        final = atomic.committed_dir(self.store.root, step)
-        atomic.commit_dir(stage, final, crash)
-        crash.maybe("before_latest_write")
-        atomic.write_latest(self.store.root, step, crash)
-        # COMMIT phase: the coordinator publishes the round's aggregated
-        # chunk refcounts atomically; the digests are captured first so the
-        # new objects can be drained to the slow tier below
-        coord = self.coordinator
-        round_digests = sorted(coord.round.chunk_refs) if coord.round else []
-        coord.finish_round(
-            True,
-            publish_refs=(
-                (lambda refs: self.chunks.apply_refs(refs, crash))
-                if incremental else None))
-        commit_total()
-        for hook in list(self.on_commit):
-            # announcement plane: distribution is best-effort, durability
-            # is not — a publisher failure must never abort a committed
-            # save
-            try:
-                hook(step, manifest)
-            except Exception as e:  # noqa: BLE001
-                warn("CKPT_W_HOOK", "on_commit hook failed",
-                     step=step, detail=f"{e.__class__.__name__}: {e}")
+        # ckpt.commit: from the phase-1 barrier to a restartable round
+        # (manifest, commit rename, LATEST, refcounts), before the hooks
+        with trace.span("ckpt.commit", step):
+            # ---- stage 2: manifest = commit record (single handle, P7) ----
+            leaf_specs = [(name, leaf.shape, str(leaf.dtype))
+                          for name, leaf in leaf_paths(state)]
+            leaves = outcome.plan.manifest_leaves(
+                leaf_specs, outcome.shard_records if incremental else None)
+            manifest = {
+                "format": FORMAT_VERSION,
+                "mode": self.mode,
+                "step": step,
+                "created": time.time(),
+                "chunk_size": self.chunks.chunk_size if incremental else None,
+                "chunking": self.chunking if incremental else None,
+                # CDC bound triple (min/avg/max): lets the inspector compare
+                # the realized chunk-size distribution against what was asked
+                "chunk_bounds": ([self._chunker.min_size,
+                                  self._chunker.avg_size,
+                                  self._chunker.max_size]
+                                 if incremental and self._chunker is not None
+                                 else None),
+                # v6: the writer's EFFECTIVE policy (codec resolved) rides the
+                # manifest, so a restarted job adopts the writer's
+                # chunking/scan/codec settings with zero caller configuration
+                "policy": self._effective_policy_dict(),
+                "leaves": leaves,
+                "registry": registry_json(registry),
+                "extra": extra,
+            }
+            degraded = bool(degraded_hint or
+                            self.chunks.degraded_writes > pre_degraded)
+            if degraded:
+                # only present when True: older readers' lenient from_dict
+                # ignores the key, and clean manifests stay byte-identical
+                manifest["degraded"] = True
+                warn("CKPT_W_DEGRADED",
+                     "round committed degraded: objects written past the "
+                     "fast tier; restore reads them from the lower tier(s)",
+                     step=step,
+                     objects=self.chunks.degraded_writes - pre_degraded)
+            crash.maybe("before_manifest")
+            atomic.atomic_write_bytes(stage / atomic.MANIFEST,
+                                      json.dumps(manifest).encode(), crash)
+            atomic.clear_pending(stage)
+            final = atomic.committed_dir(self.store.root, step)
+            atomic.commit_dir(stage, final, crash)
+            crash.maybe("before_latest_write")
+            atomic.write_latest(self.store.root, step, crash)
+            # COMMIT phase: the coordinator publishes the round's aggregated
+            # chunk refcounts atomically; the digests are captured first so the
+            # new objects can be drained to the slow tier below
+            coord = self.coordinator
+            round_digests = sorted(coord.round.chunk_refs) \
+                if coord.round else []
+            coord.finish_round(
+                True,
+                publish_refs=(
+                    (lambda refs: self.chunks.apply_refs(refs, crash))
+                    if incremental else None))
+            commit_total()
+        with trace.span("ckpt.hooks", step):
+            for hook in list(self.on_commit):
+                # announcement plane: distribution is best-effort, durability
+                # is not — a publisher failure must never abort a committed
+                # save
+                try:
+                    hook(step, manifest)
+                except Exception as e:  # noqa: BLE001
+                    warn("CKPT_W_HOOK", "on_commit hook failed",
+                         step=step, detail=f"{e.__class__.__name__}: {e}")
 
         # ---- stage 3: maintenance + slow-tier drain ----
         if overlapped and self._persist.fast_flush_requested:
@@ -541,22 +574,22 @@ class CheckpointManager:
             # or later deduped rounds would reference fast-only objects.
             self.last_gc_report = {"skipped": True, "reason": "fast-flush"}
         else:
-            self.last_gc_report = self._gc_locked(crash=crash)
-        self.store.drain_step(
-            final.name,
-            extra_files=[cas.object_rel(d, r)
-                         for d in round_digests
-                         for r in range(self.chunks.replicas)])
+            with trace.span("ckpt.gc", step):
+                self.last_gc_report = self._gc_locked(crash=crash)
+        with trace.span("ckpt.drain", step):
+            self.store.drain_step(
+                final.name,
+                extra_files=[cas.object_rel(d, r)
+                             for d in round_digests
+                             for r in range(self.chunks.replicas)])
         dt = time.monotonic() - t0
         report = {
             "step": step, "mode": self.mode, "bytes": total,
             "payload_bytes": stats["payload_bytes"],
             "written_bytes": stats["written_bytes"],
             "files": stats["files"], "seconds": dt,
-            "snapshot_s": snap_s, "drain_wait_s": wait_s,
-            "overlapped": overlapped,
+            "snapshot_s": snap_s, "overlapped": overlapped,
             "blocking_s": snap_s if overlapped else dt,
-            "throughput_gbps": total / dt / 1e9 if dt else 0.0,
             "compression_ratio": total / max(stats["payload_bytes"], 1),
             "degraded": degraded,
         }
@@ -703,27 +736,39 @@ class CheckpointManager:
         (it is the PR-1 baseline). Device arrays are built on the calling
         thread either way — JAX array construction never runs on pool
         workers."""
-        step, manifest, step_dir, plan, treedef = self._plan_restore(
-            abstract_state, shardings, step)
-        if self._restore_exec.serial:
-            prefetched = self._restore.prefetch(plan)
-            out = [self._restore.leaf_to_device(step_dir, job, pre)
-                   for job, pre in zip(plan.jobs, prefetched)]
-        else:
-            schedule, _ = plan.first_use_schedule(
-                leaf_priority, self.policy.restore.frontier_classes)
-            futures = self._restore.prefetch_async(plan, schedule)
-            try:
-                out = [self._restore.leaf_to_device(step_dir, job,
-                                                    futures[i].result())
-                       for i, job in enumerate(plan.jobs)]
-            except BaseException:
-                self._drain_futures(futures)
-                raise
-        state = jax.tree_util.tree_unflatten(treedef, out)
-        if validate:
-            validate_against(state, manifest["leaves"])
-        self._cache.clear()
+        tid = ("restore", next(_RESTORE_IDS))
+        with trace.root("ckpt.restore", tid):
+            # restore.plan: the step, the manifest, the plan, and (pipelined)
+            # the fetches' first-use order and their dispatch to the pool
+            with trace.span("restore.plan", tid):
+                step, manifest, step_dir, plan, treedef = \
+                    self._plan_restore(abstract_state, shardings, step)
+                if not self._restore_exec.serial:
+                    schedule, _ = plan.first_use_schedule(
+                        leaf_priority, self.policy.restore.frontier_classes)
+                    futures = self._restore.prefetch_async(plan, schedule,
+                                                           trace_id=tid)
+            if self._restore_exec.serial:
+                with trace.span("restore.wait", tid):
+                    prefetched = self._restore.prefetch(plan, trace_id=tid)
+                out = [self._restore.leaf_to_device(step_dir, job, pre,
+                                                    trace_id=tid)
+                       for job, pre in zip(plan.jobs, prefetched)]
+            else:
+                try:
+                    out = []
+                    for i, job in enumerate(plan.jobs):
+                        with trace.span("restore.wait", tid):
+                            pre = futures[i].result()
+                        out.append(self._restore.leaf_to_device(
+                            step_dir, job, pre, trace_id=tid))
+                except BaseException:
+                    self._drain_futures(futures)
+                    raise
+            state = jax.tree_util.tree_unflatten(treedef, out)
+            if validate:
+                validate_against(state, manifest["leaves"])
+            self._cache.clear()
         return state, manifest.get("extra", {})
 
     def restore_streaming(self, abstract_state, shardings=None, *,
@@ -738,12 +783,22 @@ class CheckpointManager:
         final ``stream.state()`` completion gate — blocks on that leaf's
         future, so the restored state is bit-exact with the blocking path
         by construction. Registry validation and the read-cache release
-        run once, inside the completion gate."""
-        _, manifest, _, plan, treedef = self._plan_restore(
-            abstract_state, shardings, step)
-        schedule, frontier = plan.first_use_schedule(
-            leaf_priority, self.policy.restore.frontier_classes)
-        futures = self._restore.prefetch_async(plan, schedule)
+        run once, inside the completion gate. The ``ckpt.restore`` trace
+        root lasts until the gate, or until a leaf fails or the stream is
+        dropped."""
+        tid = ("restore", next(_RESTORE_IDS))
+        root = trace.open_root("ckpt.restore", tid)
+        try:
+            with trace.span("restore.plan", tid):
+                _, manifest, _, plan, treedef = self._plan_restore(
+                    abstract_state, shardings, step)
+                schedule, frontier = plan.first_use_schedule(
+                    leaf_priority, self.policy.restore.frontier_classes)
+                futures = self._restore.prefetch_async(plan, schedule,
+                                                       trace_id=tid)
+        except BaseException as e:
+            trace.close_root(root, e)
+            raise
 
         def finalize(state):
             if validate:
@@ -751,7 +806,8 @@ class CheckpointManager:
             self._cache.clear()
 
         stream = RestoreStream(self._restore, plan, futures, treedef,
-                               schedule, frontier, finalize=finalize)
+                               schedule, frontier, finalize=finalize,
+                               trace_root=root)
         return stream, manifest.get("extra", {})
 
     # ------------------------------------------------------------------

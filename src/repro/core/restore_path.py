@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 import zlib
 from collections import OrderedDict
 
@@ -32,7 +33,7 @@ import msgpack
 import numpy as np
 
 from . import codec as codec_mod
-from . import resilience
+from . import resilience, trace
 from .elastic import (ShardRange, assemble, leaf_first_use_class,
                       normalize_index, plan_reads)
 from .errors import CorruptShardError, MissingShardError, warn
@@ -190,22 +191,26 @@ class RestoreSession:
         self.cache = cache
 
     # -- leaf-level ----------------------------------------------------
-    def fetch_host(self, step_dir: str, job) -> dict:
+    def fetch_host(self, step_dir: str, job, trace_id=None) -> dict:
         """One leaf's host-side fetch: {range key → host array} for every
-        range THIS process needs. Pool-worker safe (pure numpy + IO)."""
+        range THIS process needs. Pool-worker safe (pure numpy + IO).
+        ``trace_id`` names the ``ckpt.restore`` trace root its read and
+        decode spans belong to."""
         name, rec, sds, sharding, np_dtype = job
-        fetch = self.leaf_fetcher(step_dir, name, rec, np_dtype)
+        fetch = self.leaf_fetcher(step_dir, name, rec, np_dtype, trace_id)
         shape = tuple(sds.shape)
         return {(rng.start, rng.stop): fetch(rng)
                 for rng in RestorePlan.leaf_ranges(shape, sharding)}
 
-    def prefetch(self, plan: RestorePlan) -> list:
+    def prefetch(self, plan: RestorePlan, trace_id=None) -> list:
         """Phase 1 (blocking): fan the per-leaf host fetches out across
         the restore pool; returns, per job, {range key → host array}."""
         return self.executor.map_ordered(
-            lambda job: self.fetch_host(plan.step_dir, job), plan.jobs)
+            lambda job: self.fetch_host(plan.step_dir, job, trace_id),
+            plan.jobs)
 
-    def prefetch_async(self, plan: RestorePlan, schedule=None) -> list:
+    def prefetch_async(self, plan: RestorePlan, schedule=None,
+                       trace_id=None) -> list:
         """Phase 1, streaming: dispatch every per-leaf host fetch and
         return its future — indexed by JOB position, submitted in
         `schedule` order (first-use), so pool workers drain the frontier
@@ -217,13 +222,17 @@ class RestoreSession:
         for i in (schedule if schedule is not None
                   else range(len(plan.jobs))):
             futures[i] = self.executor.submit(
-                self.fetch_host, plan.step_dir, plan.jobs[i])
+                self.fetch_host, plan.step_dir, plan.jobs[i], trace_id)
         return futures
 
-    def leaf_to_device(self, step_dir, job, prefetched):
+    def leaf_to_device(self, step_dir, job, prefetched, trace_id=None):
         """Phase 2 (MAIN thread only): device array from prefetched host
         data, with a lazy fetch fallback for ranges the prefetch missed.
         JAX array construction never runs on pool workers."""
+        with trace.span("restore.place", trace_id):
+            return self._to_device(step_dir, job, prefetched, trace_id)
+
+    def _to_device(self, step_dir, job, prefetched, trace_id):
         import jax
         name, rec, sds, sharding, np_dtype = job
         shape = tuple(sds.shape)
@@ -231,7 +240,7 @@ class RestoreSession:
         if sharding is None:
             full = prefetched[((0,) * len(shape), shape)]
             return jax.numpy.asarray(full, dtype=dtype)
-        fetch = self.leaf_fetcher(step_dir, name, rec, np_dtype)
+        fetch = self.leaf_fetcher(step_dir, name, rec, np_dtype, trace_id)
 
         def cb(index):
             rng = normalize_index(index, shape)
@@ -242,7 +251,7 @@ class RestoreSession:
 
         return jax.make_array_from_callback(shape, sharding, cb)
 
-    def leaf_fetcher(self, step_dir, name, rec, np_dtype):
+    def leaf_fetcher(self, step_dir, name, rec, np_dtype, trace_id=None):
         """Host-side range fetch for one leaf: plan reads over the saved
         shard ranges, read/decode each, assemble the target range.
 
@@ -260,11 +269,11 @@ class RestoreSession:
             if exact_ok and len(picks) == 1 and \
                     picks[0][0].start == target.start and \
                     picks[0][0].stop == target.stop:
-                arr = self.read_shard(step_dir, picks[0][1])
+                arr = self.read_shard(step_dir, picks[0][1], trace_id)
                 if arr.dtype == np_dtype and arr.shape == target.shape:
                     return arr
                 # dtype/shape drift: fall through to the casting assemble
-            pieces = [(rng, self.read_shard(step_dir, s))
+            pieces = [(rng, self.read_shard(step_dir, s, trace_id))
                       for rng, s in picks]
             try:
                 return assemble(target, pieces, np_dtype)
@@ -274,9 +283,10 @@ class RestoreSession:
         return fetch
 
     # -- shard-level ---------------------------------------------------
-    def read_shard(self, step_dir: str, srec: dict) -> np.ndarray:
+    def read_shard(self, step_dir: str, srec: dict,
+                   trace_id=None) -> np.ndarray:
         if "chunks" in srec:
-            return self.read_chunked_shard(srec)
+            return self.read_chunked_shard(srec, trace_id)
         # step-scoped: shard file names repeat across steps, and a failed
         # restore can leave the cache populated for a different step
         key = f"{step_dir}/{srec['file']}"
@@ -292,15 +302,17 @@ class RestoreSession:
                                              file=fname)
                 continue
             try:
-                if self.chunks.retry is not None:
-                    raw = resilience.retry_io(
-                        lambda: tier.read_file(rel), self.chunks.retry,
-                        deadline=self.chunks._deadline,
-                        health=self.store.health_for(tier),
-                        op="shard_read")
-                else:
-                    raw = tier.read_file(rel)
-                rng, arr = unpack_shard(raw)
+                with trace.span("restore.read", trace_id):
+                    if self.chunks.retry is not None:
+                        raw = resilience.retry_io(
+                            lambda: tier.read_file(rel), self.chunks.retry,
+                            deadline=self.chunks._deadline,
+                            health=self.store.health_for(tier),
+                            op="shard_read")
+                    else:
+                        raw = tier.read_file(rel)
+                with trace.span("restore.decode", trace_id):
+                    rng, arr = unpack_shard(raw)
                 if fname != srec["file"]:
                     warn("CKPT_W_REPLICA", "primary shard unavailable; "
                          "restored from buddy replica", file=srec["file"])
@@ -312,7 +324,7 @@ class RestoreSession:
         raise last_err if last_err else MissingShardError(
             "unreadable shard", file=srec["file"])
 
-    def read_chunked_shard(self, srec: dict) -> np.ndarray:
+    def read_chunked_shard(self, srec: dict, trace_id=None) -> np.ndarray:
         """v3/v4/v5 incremental shard: reassemble the encoded payload via
         the prefetch pipeline (each chunk resolved fast tier → slow tier →
         buddy replica, the whole-payload crc as the end-to-end integrity
@@ -337,6 +349,16 @@ class RestoreSession:
         cached = self.cache.get(key)
         if cached is not None:
             return cached
+        # restore.read holds the tier reads and the whole-payload crc gate
+        with trace.span("restore.read", trace_id):
+            payload = self._read_payload(srec)
+        with trace.span("restore.decode", trace_id):
+            arr = self._decode(srec, payload)
+        self.cache.put(key, arr)
+        return arr
+
+    def _read_payload(self, srec: dict):
+        """The record's encoded payload, reassembled from its chunks."""
         fixed = srec.get("chunking", "fixed") == "fixed"
         chunk_size = srec.get("chunk_size") or 0
         chunk_lens = srec.get("chunk_lens")
@@ -349,19 +371,24 @@ class RestoreSession:
             # lengths, so direct placement (and its crc-gated verified
             # fallback inside read_payload_direct) reassembles exactly
             # the stored entropy-coded stream
-            payload = self.chunks.read_payload_direct(
+            return self.chunks.read_payload_direct(
                 srec["chunks"], payload_bytes, crc32, chunk_lens)
-        elif fixed and chunk_size > 0 and payload_bytes is not None \
+        if fixed and chunk_size > 0 and payload_bytes is not None \
                 and crc32 is not None:
-            payload = self.chunks.read_payload_fixed(
+            return self.chunks.read_payload_fixed(
                 srec["chunks"], payload_bytes, chunk_size, crc32)
-        elif chunk_lens is not None and payload_bytes is not None \
+        if chunk_lens is not None and payload_bytes is not None \
                 and crc32 is not None:
-            payload = self.chunks.read_payload_direct(
+            return self.chunks.read_payload_direct(
                 srec["chunks"], payload_bytes, crc32, chunk_lens)
-        else:
-            payload = self.chunks.read_payload(srec["chunks"],
-                                               payload_bytes, crc32=crc32)
+        return self.chunks.read_payload(srec["chunks"], payload_bytes,
+                                        crc32=crc32)
+
+    @staticmethod
+    def _decode(srec: dict, payload) -> np.ndarray:
+        """The record's array from its reassembled payload."""
+        chunk_lens = srec.get("chunk_lens")
+        chunk_raw_lens = srec.get("chunk_raw_lens")
         rng = ShardRange(tuple(srec["start"]), tuple(srec["stop"]))
         if chunk_raw_lens is not None \
                 and srec["codec"] in codec_mod.CHUNK_ENCODED:
@@ -375,13 +402,10 @@ class RestoreSession:
             k = int(meta.get("bp")
                     or codec_mod._np_dtype(srec["dtype"]).itemsize)
             raw = codec_mod.byteplane_inverse(t, k)
-            arr = raw.view(codec_mod._np_dtype(srec["dtype"])) \
+            return raw.view(codec_mod._np_dtype(srec["dtype"])) \
                 .reshape(rng.shape)
-        else:
-            arr = codec_mod.decode(payload, srec["codec"], rng.shape,
-                                   srec["dtype"], srec.get("meta", {}))
-        self.cache.put(key, arr)
-        return arr
+        return codec_mod.decode(payload, srec["codec"], rng.shape,
+                                srec["dtype"], srec.get("meta", {}))
 
 
 class RestoreStream:
@@ -409,7 +433,7 @@ class RestoreStream:
 
     def __init__(self, session: RestoreSession, plan: RestorePlan,
                  futures: list, treedef, schedule: list, frontier: list,
-                 finalize=None):
+                 finalize=None, trace_root: trace.Root | None = None):
         self._session = session
         self._plan = plan
         self._futures = futures
@@ -417,6 +441,15 @@ class RestoreStream:
         self._schedule = schedule
         self._frontier = frontier
         self._finalize = finalize      # validation + cache clear, once
+        # the ckpt.restore root: closed by the completion gate, by a
+        # failed leaf, or when the stream is dropped before either
+        self._root = trace_root
+        self._trace_id = trace_root.trace_id if trace_root else None
+        if trace_root is not None:
+            weakref.finalize(
+                self, trace.close_root, trace_root,
+                ReferenceError("restore stream dropped before its "
+                               "completion gate"))
         self._placed: dict = {}
         self._state = None
 
@@ -446,9 +479,15 @@ class RestoreStream:
 
     def _place(self, i: int):
         if i not in self._placed:
-            pre = self._futures[i].result()     # the completion gate
-            self._placed[i] = self._session.leaf_to_device(
-                self._plan.step_dir, self._plan.jobs[i], pre)
+            try:
+                with trace.span("restore.wait", self._trace_id):
+                    pre = self._futures[i].result()  # the completion gate
+                self._placed[i] = self._session.leaf_to_device(
+                    self._plan.step_dir, self._plan.jobs[i], pre,
+                    trace_id=self._trace_id)
+            except BaseException as e:
+                self._close(e)
+                raise
         return self._placed[i]
 
     def wait_frontier(self):
@@ -488,6 +527,15 @@ class RestoreStream:
         import jax
         state = jax.tree_util.tree_unflatten(self._treedef, out)
         if self._finalize is not None:
-            self._finalize(state)
+            try:
+                self._finalize(state)
+            except BaseException as e:
+                self._close(e)
+                raise
+        self._close()
         self._state = state
         return state
+
+    def _close(self, error: BaseException | None = None):
+        if self._root is not None:
+            trace.close_root(self._root, error)

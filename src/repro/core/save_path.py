@@ -37,7 +37,7 @@ import msgpack
 import numpy as np
 
 from . import codec as codec_mod
-from . import resilience
+from . import resilience, trace
 from .atomic import NO_CRASH, CrashInjector
 from .cas import ChunkStore, chunk_digest, split_payload
 from .cas import run_chunker as cas_run_chunker
@@ -202,12 +202,18 @@ class SaveSession:
     The serial engine (``io_threads=1``) bypasses the queue entirely:
     ``submit_payload`` runs the original chunk-at-a-time ``put_payload``
     inline, so the PR-1 baseline stays byte-for-byte intact.
+
+    ``trace_id`` (the round's step) names the ``ckpt.persist`` trace root
+    the session's spans and its ``scan_bytes`` go to: ``ckpt.store`` per
+    chunk (on the pool; inline on the serial engine), ``ckpt.scan_wait``
+    and ``ckpt.fsync`` on the writer.
     """
 
     def __init__(self, chunks: ChunkStore, *, crash: CrashInjector = NO_CRASH,
                  on_chunk=None, chunker=None, dirs: set | None = None,
-                 window: int | None = None):
+                 window: int | None = None, trace_id=None):
         self._chunks = chunks
+        self._trace_id = trace_id
         self._crash = crash
         self._on_chunk = on_chunk
         self._chunker = chunker
@@ -235,10 +241,13 @@ class SaveSession:
         at flush/result) — so the scan of payload k+1 overlaps the chunk
         hash/write of payload k instead of serializing in front of it."""
         if self.serial:
+            if self._chunker_obj is not None:
+                self._note_scan(len(payload))
             lens: list = []
             digests, new = self._chunks.put_payload(
                 payload, self._crash, on_chunk=self._on_chunk,
-                chunker=self._chunker, lens_out=lens)
+                chunker=self._chunker, lens_out=lens,
+                trace_id=self._trace_id)
             ticket = PayloadTicket(0, len(payload))
             ticket.digests = digests
             ticket.lens = lens
@@ -250,13 +259,14 @@ class SaveSession:
             ticket = PayloadTicket(-1, len(payload), submitted=False)
             try:
                 handle = self._chunker_obj.scanner.scan_async(payload)
+                self._note_scan(len(payload))
 
                 def resolve(payload=payload, handle=handle):
                     # a multi-GB payload scans as hundreds of segments:
                     # beat per segment, not once per payload
                     return payload, self._chunker_obj.chunk(
-                        payload,
-                        candidates=handle.result(on_segment=self._on_chunk))
+                        payload, candidates=self._scan_wait(
+                            handle, on_segment=self._on_chunk))
 
                 self._enqueue_scan(resolve, ticket)
             except BaseException:
@@ -309,9 +319,10 @@ class SaveSession:
                     # below the acceleration threshold — same bytes)
                     handle = ck.scanner.scan_transform_encode_async(
                         payload, itemsize, codec_name)
+                    self._note_scan(n)
 
                     def resolve(handle=handle, ck=ck, ticket=ticket, n=n):
-                        cands, stream, block_lens = handle.result()
+                        cands, stream, block_lens = self._scan_wait(handle)
                         cuts = ck.align_cuts(ck.cut_points_n(n, cands), n,
                                              codec_mod.ENTROPY_BLOCK)
                         chunks, ticket.raw_lens = \
@@ -321,10 +332,11 @@ class SaveSession:
                     # device transform + scan, host entropy stage
                     handle = ck.scanner.scan_transform_async(
                         payload, itemsize)
+                    self._note_scan(n)
 
                     def resolve(handle=handle, ck=ck, ticket=ticket,
                                 codec_name=codec_name):
-                        cands, t = handle.result()
+                        cands, t = self._scan_wait(handle)
                         cuts = ck.align_cuts(
                             ck.cut_points_n(len(t), cands), len(t),
                             codec_mod.ENTROPY_BLOCK)
@@ -355,9 +367,10 @@ class SaveSession:
             elif codec_name == "byteplane" and accel:
                 handle = self._chunker_obj.scanner.scan_transform_async(
                     payload, itemsize)
+                self._note_scan(n)
 
                 def resolve(handle=handle):
-                    cands, t = handle.result()
+                    cands, t = self._scan_wait(handle)
                     return t, self._chunker_obj.chunk(t, candidates=cands)
             else:
                 from . import cdc_scan
@@ -409,7 +422,8 @@ class SaveSession:
             lens: list = []
             digests, new = self._chunks.put_payload(
                 enc_stream, self._crash, on_chunk=self._on_chunk,
-                chunker=lambda _p: chunks, lens_out=lens)
+                chunker=lambda _p: chunks, lens_out=lens,
+                trace_id=self._trace_id)
             ticket = PayloadTicket(0, len(t))
             ticket.digests = digests
             ticket.lens = lens
@@ -425,6 +439,19 @@ class SaveSession:
             self.abort()
             raise
         return ticket
+
+    def _note_scan(self, n: int):
+        """Count a payload of ``n`` bytes sent to the Pallas gear-scan
+        kernel."""
+        from . import cdc_scan
+        if n > cdc_scan.WINDOW and \
+                self._chunker_obj.scanner.resolve(n) == "pallas":
+            trace.count(self._trace_id, scan_bytes=n)
+
+    def _scan_wait(self, handle, **kw):
+        """The writer blocked on a device scan's result."""
+        with trace.span("ckpt.scan_wait", self._trace_id):
+            return handle.result(**kw)
 
     def _enqueue_scan(self, resolve, ticket: PayloadTicket):
         self._scan_queue.append((resolve, ticket))
@@ -464,9 +491,10 @@ class SaveSession:
             raise
 
     def _store(self, chunk):
-        d = chunk_digest(chunk)
-        return d, self._chunks.store_chunk(d, chunk, self._crash,
-                                           self.dirs, self._dirs_lock)
+        with trace.span("ckpt.store", self._trace_id):
+            d = chunk_digest(chunk)
+            return d, self._chunks.store_chunk(d, chunk, self._crash,
+                                               self.dirs, self._dirs_lock)
 
     # -- consumption ---------------------------------------------------
     def _consume_one(self):
@@ -532,7 +560,8 @@ class SaveSession:
         fan-out dir this session touched."""
         self.flush()
         if self.dirs:
-            self._chunks.fsync_dirs(self.dirs, crash or self._crash)
+            with trace.span("ckpt.fsync", self._trace_id):
+                self._chunks.fsync_dirs(self.dirs, crash or self._crash)
             self.dirs.clear()
 
 
@@ -606,7 +635,7 @@ def write_shards(*, items, alive_hint: int, coordinator, chunks: ChunkStore,
             rank_chunks: Counter = Counter()
             session = SaveSession(chunks, crash=crash,
                                   on_chunk=lambda: coordinator.heartbeat(rank),
-                                  chunker=chunker)
+                                  chunker=chunker, trace_id=step)
             deferred: list = []             # (item index, ticket, record)
             for i, name, rng, arr, fname, is_replica in work:
                 codec_name = leaf_codec(name)
@@ -651,11 +680,12 @@ def write_shards(*, items, alive_hint: int, coordinator, chunks: ChunkStore,
                                 .reshape(-1).view(np.uint8)
                             meta = {}
                         else:
-                            payload, meta = call_with_heartbeat(
-                                lambda a=arr, c=codec_name:
-                                    codec_mod.encode(a, c),
-                                lambda: coordinator.heartbeat(rank),
-                                coordinator.keepalive_s / 4)
+                            with trace.span("ckpt.encode", step):
+                                payload, meta = call_with_heartbeat(
+                                    lambda a=arr, c=codec_name:
+                                        codec_mod.encode(a, c),
+                                    lambda: coordinator.heartbeat(rank),
+                                    coordinator.keepalive_s / 4)
                         crash.maybe(f"rank{rank}_before_write")
                         ticket = session.submit_payload(payload)
                     rec = {
@@ -672,21 +702,26 @@ def write_shards(*, items, alive_hint: int, coordinator, chunks: ChunkStore,
                     }
                     deferred.append((i, ticket, rec))
                 else:
-                    data, header = pack_shard(name, rng, arr, codec_name)
+                    with trace.span("ckpt.encode", step):
+                        data, header = pack_shard(name, rng, arr,
+                                                  codec_name)
                     crash.maybe(f"rank{rank}_before_write")
                     # full-mode shard files get the bounded retry but NOT
                     # the degraded failover: the commit path renames the
                     # staging dir within the fast root, so a shard landed
                     # on another tier could never be committed
-                    if chunks.retry is not None:
-                        resilience.retry_io(
-                            lambda d=data, f=fname: store.fast.write_file(
-                                f"{rel_stage}/{f}", d),
-                            chunks.retry, deadline=chunks._deadline,
-                            health=store.health_for(store.fast),
-                            op="shard_write")
-                    else:
-                        store.fast.write_file(f"{rel_stage}/{fname}", data)
+                    with trace.span("ckpt.store", step):
+                        if chunks.retry is not None:
+                            resilience.retry_io(
+                                lambda d=data, f=fname:
+                                    store.fast.write_file(
+                                        f"{rel_stage}/{f}", d),
+                                chunks.retry, deadline=chunks._deadline,
+                                health=store.health_for(store.fast),
+                                op="shard_write")
+                        else:
+                            store.fast.write_file(f"{rel_stage}/{fname}",
+                                                  data)
                     nbytes += len(data)
                     files.append(fname)
                     with stats_lock:

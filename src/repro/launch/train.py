@@ -11,9 +11,26 @@ import argparse
 import logging
 
 from ..configs import ARCH_IDS, get_config, reduced
+from ..core import trace
 from ..core.codec import CODECS
 from ..train.loop import Trainer, TrainerConfig
 from .compile_cache import enable_compile_cache
+
+
+PERSIST_STAGES = ("ckpt.encode", "ckpt.scan_wait", "ckpt.store",
+                  "ckpt.fsync", "ckpt.commit", "ckpt.hooks", "ckpt.gc",
+                  "ckpt.drain")
+
+
+def persist_stages(step) -> str:
+    """`` stages: encode=…s …``: the seconds of the round of ``step`` in
+    which some thread was inside each persist stage (its trace record)."""
+    roots = [r for r in trace.finished("ckpt.persist") if r.trace_id == step]
+    if not roots:
+        return ""
+    return " stages:" + "".join(
+        f" {name.split('.', 1)[1]}={roots[-1].union_s(name):.3f}s"
+        for name in PERSIST_STAGES)
 
 
 def main(argv=None):
@@ -108,7 +125,8 @@ def main(argv=None):
     if last:
         print(f"last ckpt: step={last['step']} persist={last['seconds']:.3f}s"
               f" blocked={last.get('blocking_s', last['seconds']):.3f}s"
-              f" overlapped={last.get('overlapped', False)}")
+              f" overlapped={last.get('overlapped', False)}"
+              f"{persist_stages(last['step'])}")
     if report["history"]:
         print("final:", report["history"][-1])
     return 0
