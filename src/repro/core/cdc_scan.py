@@ -196,7 +196,8 @@ class _StagingArena:
     chunk-scan small-payload gap: a 2 MiB dispatch stops paying the
     fresh-allocation page-zeroing that dominated its fixed overhead."""
 
-    MAX_PER_SIZE = 4    # idle buffers kept per size (≥ in-flight window)
+    MAX_PER_SIZE = 16   # idle buffers kept per size (≥ a ticket's
+                        # in-flight window, MAX_INFLIGHT_SEGMENTS)
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -603,9 +604,17 @@ def transform_async(payload, itemsize: int) -> TransformTicket:
 # the scanner
 # ---------------------------------------------------------------------------
 
-MAX_INFLIGHT_SEGMENTS = 3    # bounds live staging+result memory: a large
-                             # payload scans as a pipeline of cache-sized
-                             # segments, not one giant working set
+MAX_INFLIGHT_SEGMENTS = 16   # a ticket's segments dispatched and not yet
+                             # extracted: 16 device buffers of 4.06 MiB
+                             # (the scan's output aliases its donated
+                             # input) and their staging, per ticket
+
+
+def _ready(res) -> bool:
+    """Whether a dispatch's device outputs are computed: extracting them
+    then waits for no device work, only the copy."""
+    return all(a.is_ready() for a in (res if isinstance(res, tuple)
+                                      else (res,)))
 
 
 class ScanTicket:
@@ -616,10 +625,16 @@ class ScanTicket:
     Dispatch is WINDOWED: the first ``MAX_INFLIGHT_SEGMENTS`` segments
     are launched by ``scan_async`` (so device work overlaps whatever the
     caller does next); the rest launch from ``result()`` as earlier
-    segments extract, keeping at most a few segments of staging buffers
-    and device results alive at once."""
+    segments extract. The window is wide because a device shares one
+    queue of programs with training: a segment launched behind a queued
+    train step runs only after it, so a ticket gets about one window
+    through per two steps. ``blocked`` counts the extractions whose
+    device result was not yet computed when ``result()`` reached it: the
+    device round trips the caller waited for (None on a ticket resolved
+    on the host)."""
 
-    __slots__ = ("_pending", "_todo", "_dispatch", "_extract", "_done")
+    __slots__ = ("_pending", "_todo", "_dispatch", "_extract", "_done",
+                 "blocked")
 
     def __init__(self, pending, todo, dispatch, extract, done=None):
         self._pending = pending         # deque of ((result, buf), start, len, n)
@@ -627,6 +642,7 @@ class ScanTicket:
         self._dispatch = dispatch
         self._extract = extract
         self._done = done               # eager backends resolve immediately
+        self.blocked = None if done is not None else 0
 
     def result(self, on_segment=None):
         """``on_segment()``, if given, is called after each segment is
@@ -635,6 +651,8 @@ class ScanTicket:
             strict, loose = [], []
             while self._pending:
                 (res, buf), start, seg_len, total = self._pending.popleft()
+                if not _ready(res):
+                    self.blocked += 1
                 s, l = self._extract(res, start, seg_len, total)
                 # extraction materialized the device outputs, so the
                 # dispatch that could alias this staging buffer is done —

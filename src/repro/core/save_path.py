@@ -204,18 +204,21 @@ class SaveSession:
     inline, so the PR-1 baseline stays byte-for-byte intact.
 
     ``trace_id`` (the round's step) names the ``ckpt.persist`` trace root
-    the session's spans and its ``scan_bytes`` go to: ``ckpt.store`` per
-    chunk (on the pool; inline on the serial engine), ``ckpt.scan_wait``
-    and ``ckpt.fsync`` on the writer.
+    the session's spans and its ``scan_bytes`` and ``scan_blocked`` (a
+    segmented scan's extractions that found the device not done) go to:
+    ``ckpt.store`` per chunk (on the pool; inline on the serial engine),
+    ``ckpt.scan_wait`` and ``ckpt.fsync`` on the writer.
     """
 
     def __init__(self, chunks: ChunkStore, *, crash: CrashInjector = NO_CRASH,
                  on_chunk=None, chunker=None, dirs: set | None = None,
-                 window: int | None = None, trace_id=None):
+                 window: int | None = None, trace_id=None,
+                 beat_s: float | None = None):
         self._chunks = chunks
         self._trace_id = trace_id
         self._crash = crash
         self._on_chunk = on_chunk
+        self._beat_s = beat_s
         self._chunker = chunker
         # a chunker OBJECT (cdc.GearChunker) exposes the async candidate
         # scanner — that unlocks the scan-ahead queue below; a plain
@@ -258,15 +261,20 @@ class SaveSession:
                 self._chunker_obj.scanner.resolve(len(payload)) != "numpy":
             ticket = PayloadTicket(-1, len(payload), submitted=False)
             try:
-                handle = self._chunker_obj.scanner.scan_async(payload)
+                handle = self._scan_wait(
+                    lambda: self._chunker_obj.scanner.scan_async(payload))
                 self._note_scan(len(payload))
 
                 def resolve(payload=payload, handle=handle):
                     # a multi-GB payload scans as hundreds of segments:
                     # beat per segment, not once per payload
+                    cands = self._scan_wait(
+                        lambda: handle.result(on_segment=self._on_chunk))
+                    if handle.blocked is not None:
+                        trace.count(self._trace_id,
+                                    scan_blocked=handle.blocked)
                     return payload, self._chunker_obj.chunk(
-                        payload, candidates=self._scan_wait(
-                            handle, on_segment=self._on_chunk))
+                        payload, candidates=cands)
 
                 self._enqueue_scan(resolve, ticket)
             except BaseException:
@@ -322,7 +330,8 @@ class SaveSession:
                     self._note_scan(n)
 
                     def resolve(handle=handle, ck=ck, ticket=ticket, n=n):
-                        cands, stream, block_lens = self._scan_wait(handle)
+                        cands, stream, block_lens = self._scan_wait(
+                            handle.result)
                         cuts = ck.align_cuts(ck.cut_points_n(n, cands), n,
                                              codec_mod.ENTROPY_BLOCK)
                         chunks, ticket.raw_lens = \
@@ -336,7 +345,7 @@ class SaveSession:
 
                     def resolve(handle=handle, ck=ck, ticket=ticket,
                                 codec_name=codec_name):
-                        cands, t = self._scan_wait(handle)
+                        cands, t = self._scan_wait(handle.result)
                         cuts = ck.align_cuts(
                             ck.cut_points_n(len(t), cands), len(t),
                             codec_mod.ENTROPY_BLOCK)
@@ -370,7 +379,7 @@ class SaveSession:
                 self._note_scan(n)
 
                 def resolve(handle=handle):
-                    cands, t = self._scan_wait(handle)
+                    cands, t = self._scan_wait(handle.result)
                     return t, self._chunker_obj.chunk(t, candidates=cands)
             else:
                 from . import cdc_scan
@@ -448,10 +457,15 @@ class SaveSession:
                 self._chunker_obj.scanner.resolve(n) == "pallas":
             trace.count(self._trace_id, scan_bytes=n)
 
-    def _scan_wait(self, handle, **kw):
-        """The writer blocked on a device scan's result."""
+    def _scan_wait(self, call):
+        """The writer blocked on a device scan: ``call()`` launches one or
+        joins its result. A launch waits while the device's queue of
+        programs is full, behind queued train steps, for as long as they
+        run; with ``beat_s`` the writer beats ``on_chunk`` meanwhile."""
         with trace.span("ckpt.scan_wait", self._trace_id):
-            return handle.result(**kw)
+            if self._beat_s is None or self._on_chunk is None:
+                return call()
+            return call_with_heartbeat(call, self._on_chunk, self._beat_s)
 
     def _enqueue_scan(self, resolve, ticket: PayloadTicket):
         self._scan_queue.append((resolve, ticket))
@@ -635,7 +649,8 @@ def write_shards(*, items, alive_hint: int, coordinator, chunks: ChunkStore,
             rank_chunks: Counter = Counter()
             session = SaveSession(chunks, crash=crash,
                                   on_chunk=lambda: coordinator.heartbeat(rank),
-                                  chunker=chunker, trace_id=step)
+                                  chunker=chunker, trace_id=step,
+                                  beat_s=coordinator.keepalive_s / 4)
             deferred: list = []             # (item index, ticket, record)
             for i, name, rng, arr, fname, is_replica in work:
                 codec_name = leaf_codec(name)

@@ -24,13 +24,16 @@ PERSIST_STAGES = ("ckpt.encode", "ckpt.scan_wait", "ckpt.store",
 
 def persist_stages(step) -> str:
     """`` stages: encode=…s …``: the seconds of the round of ``step`` in
-    which some thread was inside each persist stage (its trace record)."""
+    which some thread was inside each persist stage (its trace record),
+    then `` scan_blocked=N`` where the round counted device scan waits."""
     roots = [r for r in trace.finished("ckpt.persist") if r.trace_id == step]
     if not roots:
         return ""
-    return " stages:" + "".join(
+    line = " stages:" + "".join(
         f" {name.split('.', 1)[1]}={roots[-1].union_s(name):.3f}s"
         for name in PERSIST_STAGES)
+    blocked = roots[-1].counters.get("scan_blocked")
+    return line if blocked is None else f"{line} scan_blocked={blocked}"
 
 
 def main(argv=None):

@@ -249,6 +249,8 @@ def test_the_launchers_last_ckpt_line_reads_the_rounds_stages(tmp_path):
         assert f" {name.split('.', 1)[1]}={persist.union_s(name):.3f}s" \
             in line
     assert "hooks=" in line
+    # host-scanned payloads only: no device scan waits to report
+    assert "scan_blocked" not in line
     assert persist_stages(14) == ""
 
 
@@ -329,6 +331,34 @@ def test_scan_counters_equal_the_payload_bytes_sent_to_the_pallas_kernel(
     c = _one("ckpt.persist", 2, t0).counters
     assert sizes and c["scan_bytes"] == sum(sizes)
     assert "ckpt.scan_wait" in _names(_one("ckpt.persist", 2, t0))
+
+
+def test_scan_blocked_counts_the_rounds_waits_on_the_device(tmp_path):
+    """``scan_blocked`` sits on the round's ``ckpt.persist`` root, counts
+    at most one wait per segment the device scanned, and the launcher's
+    "last ckpt" line prints it."""
+    from repro.launch.train import persist_stages
+    t0 = time.monotonic_ns()
+    mgr = _manager(tmp_path, n_writers=2)
+    ck = mgr._chunker
+    ck.scanner = cdc_scan.GearScanner(ck.scanner.mask_strict,
+                                      ck.scanner.mask_loose,
+                                      backend="pallas",
+                                      pallas_interpret=True)
+    state = {"w": jax.random.normal(KEY, (96, 256)),
+             "v": jax.random.normal(jax.random.fold_in(KEY, 1), (64, 128))}
+    mgr.save(state, 4)
+    records = [rec for leaf in mgr.load_manifest(4)["leaves"].values()
+               for rec in leaf["shards"]]
+    mgr.close()
+    segments = sum(-(-int(r["payload_bytes"]) // cdc_scan.SEGMENT_BYTES)
+                   for r in records if r["chunking"] == "cdc"
+                   and int(r["payload_bytes"]) > cdc_scan.WINDOW)
+    persist = _one("ckpt.persist", 4, t0)
+    blocked = persist.counters["scan_blocked"]
+    assert segments and 0 <= blocked <= segments
+    assert "scan_blocked" not in _one("ckpt.save", 4, t0).counters
+    assert persist_stages(4).endswith(f" scan_blocked={blocked}")
 
 
 def test_spans_land_on_the_profilers_host_plane(tmp_path):
