@@ -51,19 +51,20 @@ def readings(cell, seeds, *, workdir: Path, require_tpu: bool = True):
         if trainer is None:
             trainer = run_mod.make_trainer(cfg, tcfg, mesh, workdir)
         trainer.tcfg = tcfg
-        run_mod.start(trainer, cell.config, seed)
-        program = run_mod.first_steps(trainer, cell.config, seed)
+        run_mod.start(trainer, cell.config, seed, cell.bench_dir)
+        program = run_mod.first_steps(trainer, cell.config, seed,
+                                      cell.bench_dir)
         trainer.state = None
         gc.collect()
         batches = [reference.tokens(tcfg.seed, k, mix["batch"],
                                     mix["seq_len"], cfg.vocab_size)
                    for k in range(reference.STEPS)]
-        rows = mix["reference_rows"]
-        ref = reference.reference(cell.config, seed, batches, rows=rows)
-        control = reference.reference(cell.config, seed, batches, rows=rows,
-                                      precision="int8")
+        kw = {"rows": mix["reference_rows"], "bench_dir": cell.bench_dir}
+        ref = reference.reference(cell.config, seed, batches, **kw)
+        control = reference.reference(cell.config, seed, batches,
+                                      precision="int8", **kw)
         half = reference.reference(
-            cell.config, seed, [b[:len(b) // 2] for b in batches], rows=rows)
+            cell.config, seed, [b[:len(b) // 2] for b in batches], **kw)
         out = {"seed": seed, "losses": {"reference": ref.losses,
                                         "program": program.losses}}
         for name, got in (("program", program), ("control", control),

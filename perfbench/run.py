@@ -129,19 +129,22 @@ def make_trainer(cfg, tcfg, mesh, workdir: Path):
     return Trainer(cfg, tcfg, mesh=mesh, store=store)
 
 
-def start(trainer, config: dict, seed: int):
+def start(trainer, config: dict, seed: int,
+          bench_dir: Path = cells.BENCH_DIR):
     """The trainer's state at step 0, built in one jitted call from the
-    weights ``reference.init_params`` makes from ``seed``; refused where
-    they do not fit the program's layout."""
+    weights ``reference.init_params`` makes from ``seed`` and the state the
+    family declares; refused where they do not fit the program's layout."""
     import jax
     import jax.numpy as jnp
-    fam = reference.family(config["config"]["family"])
+    c = config["config"]
+    fam = reference.family(c["family"], bench_dir)
 
     def state(key):
-        params = fam.init(config["config"], key)
+        params = fam.init(c, key)
         return {"params": params, "opt": trainer.optimizer.init(params),
                 "step": jnp.zeros((), jnp.int32),
-                "rng": jax.random.key_data(jax.random.PRNGKey(0))}
+                "rng": jax.random.key_data(jax.random.PRNGKey(0)),
+                **reference.declared_state(fam, c, key)}
 
     key = reference.weight_key(seed)
     have = jax.tree.map(lambda a: (a.shape, a.dtype),
@@ -156,18 +159,21 @@ def start(trainer, config: dict, seed: int):
     return trainer
 
 
-def first_steps(trainer, config: dict, seed: int) -> "reference.Readings":
+def first_steps(trainer, config: dict, seed: int,
+                bench_dir: Path = cells.BENCH_DIR) -> "reference.Readings":
     """The first ``reference.STEPS`` steps through the window's call and
     feed, and what the reference compares of them."""
-    b1 = config["reference"]["optimizer"]["b1"]
+    opt = config["reference"]["optimizer"]
+    optim = reference.optimizer(opt["name"], bench_dir)
     losses, grad = [], None
     for k in range(reference.STEPS):
         losses.append(float(dispatch_step(trainer)))
         if k == 0:
-            grad = reference.leaf_norms(trainer.state["opt"]["m"],
-                                        1.0 / (1.0 - b1))
-    change = reference.change_norms(trainer.state["params"],
-                                    reference.init_params(config, seed))
+            grad = optim.first_grad_norms(trainer.state["opt"], opt)
+    state = {k: v for k, v in trainer.state.items()
+             if k not in reference.PROGRAM_STATE}
+    change = reference.changes(config, seed, trainer.state["params"], state,
+                               bench_dir=bench_dir)
     return reference.Readings(losses, grad, change)
 
 
@@ -292,11 +298,11 @@ def _run(run, cell, cfg, tcfg, mesh, used, seconds, trace, trace_dir,
     # ---------------- set-up ----------------
     t = time.monotonic()
     trainer = start(make_trainer(cfg, tcfg, mesh, workdir), cell.config,
-                    seed)
+                    seed, cell.bench_dir)
     jax.block_until_ready(trainer.state)
     t_init = time.monotonic() - t
     t = time.monotonic()
-    program = first_steps(trainer, cell.config, seed)
+    program = first_steps(trainer, cell.config, seed, cell.bench_dir)
     t_warm = time.monotonic() - t
     t = time.monotonic()
     # the scanner every writer rank of this manager shares
@@ -426,7 +432,8 @@ def _run(run, cell, cfg, tcfg, mesh, used, seconds, trace, trace_dir,
                                 cfg.vocab_size)
                for k in range(reference.STEPS)]
     ref = reference.reference(cell.config, seed, batches,
-                              rows=mix["reference_rows"])
+                              rows=mix["reference_rows"],
+                              bench_dir=cell.bench_dir)
     train = reference.gaps(program, ref)
     say(f"reference {time.monotonic() - t:.3f}s: losses {ref.losses}, the "
         f"program's {program.losses}; worst leaves "

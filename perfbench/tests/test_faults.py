@@ -53,14 +53,16 @@ def failing(res) -> set:
     return {k for k, c in res["checks"].items() if not c["value"] <= c["limit"]}
 
 
-@pytest.mark.parametrize("cell", ["tiny-mamba.incr", "tiny-dense.incr"])
+@pytest.mark.parametrize("cell", ["tiny-mamba.incr", "tiny-dense.incr",
+                                  "tiny-dense-adafactor.incr"])
 def test_sound_run_is_correct(root, cell):
     res = one_run(root, cell)
     assert res["correct"] is True
     assert all(res["checks"][k]["value"] == 0 for k in EXACT)
 
 
-@pytest.mark.parametrize("cell", ["tiny-mamba.incr", "tiny-dense.incr"])
+@pytest.mark.parametrize("cell", ["tiny-mamba.incr", "tiny-dense.incr",
+                                  "tiny-dense-adafactor.incr"])
 def test_control_reference_at_int8_is_not_correct(root, tmp_path, cell):
     cell = cells.load_cell(cell, root)
     limits = cell.config["reference"]["limits"]
@@ -108,34 +110,39 @@ def broken_step(monkeypatch, breaking):
     monkeypatch.setattr(loop, "make_train_step", make)
 
 
-def test_step_returning_its_state_unchanged_is_not_correct(root,
-                                                           monkeypatch):
+@pytest.mark.parametrize("cell", ["tiny-mamba.incr",
+                                  "tiny-dense-adafactor.incr"])
+def test_step_returning_its_state_unchanged_is_not_correct(root, monkeypatch,
+                                                           cell):
     def frozen(step, state, batch):
         _, metrics = step(state, batch)
         return state, metrics
 
     broken_step(monkeypatch, frozen)
-    res = one_run(root)
+    res = one_run(root, cell)
     assert res["correct"] is False
     assert {"grad_norm_gap", "update_norm_gap",
             "step_counter_gap"} <= failing(res)
     assert res["checks"]["grad_norm_gap"]["value"] == pytest.approx(1.0)
 
 
+@pytest.mark.parametrize("cell", ["tiny-mamba.incr",
+                                  "tiny-dense-adafactor.incr"])
 def test_update_dropped_with_the_counter_moving_is_not_correct(
-        root, monkeypatch):
+        root, monkeypatch, cell):
     def dropped(step, state, batch):
         new, metrics = step(state, batch)
         return dict(state, step=new["step"]), metrics
 
     broken_step(monkeypatch, dropped)
-    res = one_run(root)
+    res = one_run(root, cell)
     assert res["correct"] is False
     assert res["checks"]["step_counter_gap"]["value"] == 0
     assert {"grad_norm_gap", "update_norm_gap"} <= failing(res)
 
 
-@pytest.mark.parametrize("cell", ["tiny-mamba.incr", "tiny-dense.incr"])
+@pytest.mark.parametrize("cell", ["tiny-mamba.incr", "tiny-dense.incr",
+                                  "tiny-dense-adafactor.incr"])
 def test_half_the_batch_left_out_is_not_correct(root, monkeypatch, cell):
     def half(step, state, batch):
         rows = batch["tokens"].shape[0] // 2
