@@ -8,10 +8,10 @@ metric by adding files and entries, never by editing one:
   file that ``BENCHMARK.json``'s ``configs[].file`` names), with what the
   reference needs besides the sizes (``reference``: norm epsilon, the
   optimizer as stated, and the limits of the train step's comparison);
-* ``models/<family>.py``: the plain reference model of a family, with,
-  where the program's step does more than a cross-entropy step of the
-  parameters, its whole ``loss`` and the train state no gradient moves
-  (``state_init``, ``state_step``; see ``reference.py``);
+* ``models/<family>.py``: the plain reference model of a family, as
+  ``init`` and an ordered list of ``stages``, each with its term of the
+  loss, and, where the program's step keeps train state that no gradient
+  moves, ``state_init`` and ``state_step`` (see ``reference.py``);
 * ``optims/<name>.py``: the reference's optimizer, named by the
   configuration's ``reference.optimizer.name``: ``init``, ``update``, and
   ``first_grad_norms``, the first gradient read back from the program's

@@ -9,6 +9,7 @@ silu, gated or not, optional biases); both added to the residual.
 ``init`` lays the weights out as the program stores them (one stacked
 leaf per kind of weight, layers on the leading axis; RMSNorm scales as
 ``1 + scale``) so that the same tree can be handed to the program.
+``stages`` gives the model to the reference one stage at a time.
 """
 from __future__ import annotations
 
@@ -16,6 +17,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from reference import Stage, cross_entropy
 
 F32 = jnp.float32
 UNSUPPORTED = ("qk_norm", "post_norm", "embed_scale", "attn_softcap",
@@ -116,22 +119,50 @@ def mlp(c: dict, p: dict, h, mm):
     return y + p["bd"].astype(F32) if "bd" in p else y
 
 
-def forward(c: dict, p: dict, tokens, mm, stated: dict):
-    """Final hidden states (B, S, d) in float32 and the LM head (d, V)."""
-    eps = stated["norm_eps"]
-    S, hd = tokens.shape[1], c["head_dim"]
+def rope(c: dict, S: int):
+    hd = c["head_dim"]
     inv = 1.0 / c["rope_theta"] ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
     ang = jnp.arange(S, dtype=F32)[:, None] * inv              # (S, hd/2)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x = p["embed"][tokens].astype(F32)
+    return jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
 
-    @jax.checkpoint
-    def layer(x, lp):
-        x = x + attention(c, lp, normalize(c, lp["norm_in"], x, eps), mm,
-                          cos, sin)
-        return x + mlp(c, lp["mlp"], normalize(c, lp["norm_mlp"], x, eps),
-                       mm), None
 
-    x, _ = jax.lax.scan(layer, x, p["stage_0"]["b0"])
-    head = p["embed"].T if c["tie_embeddings"] else p["lm_head"]
-    return normalize(c, p["final_norm"], x, eps), head
+def embed(c: dict, i, p: dict, x, toks, mm, stated: dict, state: dict):
+    return p["embed"][toks].astype(F32), 0.0, None
+
+
+def attention_block(c: dict, i, p: dict, x, toks, mm, stated: dict,
+                    state: dict):
+    eps, lp = stated["norm_eps"], p["stage_0"]["b0"]
+    cos, sin = rope(c, toks.shape[1])
+    h = normalize(c, lp["norm_in"], x, eps)
+    return x + attention(c, lp, h, mm, cos, sin), 0.0, None
+
+
+def mlp_block(c: dict, i, p: dict, x, toks, mm, stated: dict, state: dict):
+    eps, lp = stated["norm_eps"], p["stage_0"]["b0"]
+    h = normalize(c, lp["norm_mlp"], x, eps)
+    return x + mlp(c, lp["mlp"], h, mm), 0.0, None
+
+
+def head(c: dict, i, p: dict, x, toks, mm, stated: dict, state: dict):
+    x = normalize(c, p["final_norm"], x, stated["norm_eps"])
+    w = p["embed"].T if c["tie_embeddings"] else p["lm_head"]
+    return None, cross_entropy(mm, x, w, toks), None
+
+
+def stages(c: dict) -> list:
+    """The embedding; each layer as two stages, its attention and its MLP
+    with their norms, so that a stage holds one residual branch's
+    parameters in float32; the final norm with the head and the
+    cross-entropy."""
+    layer = ("stage_0", "b0")
+    attn = [layer + (k,) for k in ("norm_in", "q", "k", "v", "o")]
+    if c["use_bias"]:
+        attn += [layer + (k,) for k in ("q_b", "k_b", "v_b", "o_b")]
+    out = ("embed",) if c["tie_embeddings"] else ("lm_head",)
+    return [Stage(embed, (("embed",),)),
+            *(Stage(fn, parts, i) for i in range(c["n_layers"])
+              for fn, parts in ((attention_block, tuple(attn)),
+                                (mlp_block, (layer + ("norm_mlp",),
+                                             layer + ("mlp",))))),
+            Stage(head, (("final_norm",), out))]

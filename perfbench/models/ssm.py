@@ -15,6 +15,7 @@ LM head.
 ``init`` lays the weights out as the program stores them (one stacked
 leaf per kind of weight, layers on the leading axis; norm scales as
 ``1 + scale``) so that the same tree can be handed to the program.
+``stages`` gives the model to the reference one stage at a time.
 """
 from __future__ import annotations
 
@@ -22,6 +23,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from reference import Stage, cross_entropy
 
 F32 = jnp.float32
 
@@ -109,15 +112,25 @@ def mixer(c: dict, p: dict, h, mm, eps):
     return mm("bse,ed->bsd", y, p["out_proj"])
 
 
-def forward(c: dict, p: dict, tokens, mm, stated: dict):
-    """Final hidden states (B, S, d) in float32 and the LM head (d, V)."""
-    eps = stated["norm_eps"]
-    x = p["embed"][tokens].astype(F32)
+def embed(c: dict, i, p: dict, x, toks, mm, stated: dict, state: dict):
+    return p["embed"][toks].astype(F32), 0.0, None
 
-    @jax.checkpoint
-    def layer(x, lp):
-        h = rmsnorm(x, lp["norm_in"]["scale"], eps)
-        return x + mixer(c, lp["ssm"], h, mm, eps), None
 
-    x, _ = jax.lax.scan(layer, x, p["stage_0"]["b0"])
-    return rmsnorm(x, p["final_norm"]["scale"], eps), p["embed"].T
+def layer(c: dict, i, p: dict, x, toks, mm, stated: dict, state: dict):
+    eps, lp = stated["norm_eps"], p["stage_0"]["b0"]
+    h = rmsnorm(x, lp["norm_in"]["scale"], eps)
+    return x + mixer(c, lp["ssm"], h, mm, eps), 0.0, None
+
+
+def head(c: dict, i, p: dict, x, toks, mm, stated: dict, state: dict):
+    x = rmsnorm(x, p["final_norm"]["scale"], stated["norm_eps"])
+    return None, cross_entropy(mm, x, p["embed"].T, toks), None
+
+
+def stages(c: dict) -> list:
+    """The embedding, each layer, and the final norm with the tied head
+    and the cross-entropy."""
+    return [Stage(embed, (("embed",),)),
+            *(Stage(layer, (("stage_0", "b0"),), i)
+              for i in range(c["n_layers"])),
+            Stage(head, (("final_norm",), ("embed",)))]

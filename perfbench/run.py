@@ -435,7 +435,8 @@ def _run(run, cell, cfg, tcfg, mesh, used, seconds, trace, trace_dir,
                               rows=mix["reference_rows"],
                               bench_dir=cell.bench_dir)
     train = reference.gaps(program, ref)
-    say(f"reference {time.monotonic() - t:.3f}s: losses {ref.losses}, the "
+    say(f"reference {time.monotonic() - t:.3f}s, largest program "
+        f"{ref.peak_bytes} B: losses {ref.losses}, the "
         f"program's {program.losses}; worst leaves "
         f"{train['worst_leaves']}; left out {train['left_out']}")
 
